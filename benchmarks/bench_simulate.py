@@ -39,8 +39,8 @@ on. Components:
                    engine's chunked row commits. Parity (accept masks,
                    trace times/values, final spends) is asserted outside
                    the timed region; the jit compile is warmed outside it
-                   too. Skipped (not failed) when no jax backend can
-                   dispatch — the committed baseline is recorded with one.
+                   too. Runs on the platform JAX initialized, which the
+                   report names (``backend``).
   fused_campaign   whole tuning campaigns on the device-resident fused
                    executor (``core.engine_jax.campaign.drive_fused``,
                    scores-only ``materialize=False`` consumption) vs the
@@ -50,10 +50,8 @@ on. Components:
                    ratio isolates the campaign loop rather than shared
                    host strategy stepping). Per-run improvements, fresh
                    evals, and budget spends are asserted bit-identical to
-                   the numpy oracle outside the timed region. Skipped
-                   (not failed) without a jax backend; the committed
-                   baseline is recorded with one, and CI floors the
-                   ratio at 10x (``check_regression.py``).
+                   the numpy oracle outside the timed region. CI floors
+                   the ratio at 10x (``check_regression.py``).
   hub_lookup       warmed ``service.ConfigHub`` exact-hit lookups (a dict
                    probe of the precomputed per-entry best) vs the naive
                    answer path a caller without the service pays per call:
@@ -700,12 +698,10 @@ def bench_jax_replay(cache: CacheFile) -> dict:
     ``speedup`` ratio is measured same-host/same-process like every other
     component, so the CI floor transfers across runner silicon.
     """
+    import jax
+
     from repro.core import engine_jax
     from repro.core.space import RowBatch
-    if not engine_jax.engine_available():
-        return {"skipped": True,
-                "reason": engine_jax.unavailable_reason()}
-    import jax
 
     compiled = cache.space.compiled
     cols = cache.columns
@@ -717,9 +713,8 @@ def bench_jax_replay(cache: CacheFile) -> dict:
     tables = engine_jax.replay_tables(cols, compiled)
 
     def jax_side():
-        out = engine_jax.replay_many(cols, compiled, rows, tables=tables)
-        jax.block_until_ready(out)
-        return out
+        # host arrays: the copy back waits for the device
+        return engine_jax.replay_many(cols, compiled, rows, tables=tables)
 
     def numpy_side():
         runners = []
@@ -753,7 +748,7 @@ def bench_jax_replay(cache: CacheFile) -> dict:
                       evals_per_sec_scalar=n_evals / w_np,
                       n_evals=n_evals, n_runs=JAX_REPLAY_RUNS,
                       reference="numpy",
-                      backend=engine_jax.backend_name())
+                      backend=jax.devices()[0].platform)
 
 
 FUSED_CAMPAIGN_RUNS = 4  # seeds per space in the fused-campaign grid
@@ -779,12 +774,11 @@ def bench_fused_campaign() -> dict:
     Parity is asserted outside the timed region: every fused run's
     improvement step function, fresh-eval count, and committed budget
     spend must equal the numpy engine's ``drive_many`` result
-    bit-for-bit. Skipped (not failed) without a jax backend.
+    bit-for-bit.
     """
+    import jax
+
     from repro.core import engine_jax
-    if not engine_jax.engine_available():
-        return {"skipped": True,
-                "reason": engine_jax.unavailable_reason()}
     caches = _hub_caches() + [_small_cache()]
     for c in caches:
         c.columns  # mirrors + compiled spaces built outside timed region
@@ -841,7 +835,7 @@ def bench_fused_campaign() -> dict:
                       n_evals=n_evals,
                       n_runs=len(caches) * FUSED_CAMPAIGN_RUNS,
                       reference="scalar",
-                      backend=engine_jax.backend_name())
+                      backend=jax.devices()[0].platform)
 
 
 ALL_COMPONENTS = ("replay_fresh", "replay_revisit", "score_trace",
@@ -914,7 +908,6 @@ def run_bench(components: "list[str] | None" = None) -> dict:
     if "replay_fresh" in comp:
         report["evals_per_sec"] = comp["replay_fresh"]["evals_per_sec"]
     # headline: geometric mean of the per-component engine speedups
-    # (skipped components — jax_replay without a backend — stay out)
     speedups = [c["speedup"] for c in comp.values() if "speedup" in c]
     if speedups:
         report["speedup_geomean"] = float(np.exp(np.mean(np.log(speedups))))
@@ -937,9 +930,6 @@ def main(json_out: str | None = None,
     print(f"{'component':16s} "
           f"{'vectorized':>12s} {'scalar':>12s} {'speedup':>8s}")
     for name, c in comp.items():
-        if c.get("skipped"):
-            print(f"{name:16s} skipped ({c.get('reason', 'unavailable')})")
-            continue
         print(f"{name:16s} {c['wall_s']*1e3:10.1f}ms {c['wall_s_scalar']*1e3:10.1f}ms "
               f"{c['speedup']:7.2f}x")
     if "replay_fresh" in comp and "replay_revisit" in comp:
@@ -950,7 +940,7 @@ def main(json_out: str | None = None,
     if "campaign" in comp:
         print(f"campaign: {comp['campaign']['evals_per_sec']:,.0f} "
               f"fresh evals/s ({comp['campaign']['fresh_evals']} evals)")
-    if not comp.get("fused_campaign", {"skipped": True}).get("skipped"):
+    if "fused_campaign" in comp:
         print(f"fused campaign: "
               f"{comp['fused_campaign']['evals_per_sec']:,.0f} fresh "
               f"evals/s ({comp['fused_campaign']['n_evals']} evals, "

@@ -116,11 +116,6 @@ def compare(baseline: dict, current: dict, threshold: float) -> list[str]:
         if cur_c is None:
             failures.append(f"component {name!r} missing from current run")
             continue
-        if cur_c.get("skipped") or base_c.get("skipped"):
-            # optional-backend components (jax_replay) skip — with a
-            # recorded reason — on runners that cannot dispatch them;
-            # a skip is not a regression
-            continue
         # relative floor, but never below MIN_SPEEDUP (or the component's
         # own hard floor): for components whose baseline ratio is close to
         # 1x (campaign), a purely relative tolerance would wave through a

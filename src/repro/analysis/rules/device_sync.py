@@ -16,8 +16,7 @@ value was produced inside the same innermost loop's per-iteration region —
 the batched-output idiom of ``campaign._drive_group`` (dispatch in the
 loop, one bulk ``np.asarray`` per output right after it) stays clean,
 while per-element syncs of device values produced outside the loop (the
-``(np.asarray(o) for o in out)`` shape grandfathered in ``replay.py``)
-are flagged. A conversion's *result* is a host value: ``spent =
+``(np.asarray(o) for o in out)`` shape) are flagged. A conversion's *result* is a host value: ``spent =
 np.asarray(out[4])`` then ``float(spent[i])`` in a loop syncs nothing.
 """
 from __future__ import annotations
@@ -55,8 +54,12 @@ def _is_conversion(node: ast.AST) -> bool:
         return False
     if call_name(node) in _CONVERT_CALLS:
         return True
-    return (isinstance(node.func, ast.Attribute)
-            and node.func.attr in _CONVERT_METHODS)
+    if not isinstance(node.func, ast.Attribute):
+        return False
+    # a method of a converted array (``np.asarray(x).view(...)``) stays
+    # on the host
+    return (node.func.attr in _CONVERT_METHODS
+            or _is_conversion(node.func.value))
 
 
 def _target_names(target: ast.AST):

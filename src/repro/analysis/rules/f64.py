@@ -87,8 +87,8 @@ class Float32Literal(Rule):
     invariant = ("the replay tables and commit path are float64 "
                  "end-to-end; a float32 cast silently truncates the "
                  "cache's charge/time columns")
-    oracle = ("float64 device mirrors asserted by table construction "
-              "under enable_x64 (core/engine_jax/tables.py) + replay "
+    oracle = ("float64 columns mirrored as int64 bit patterns under "
+              "enable_x64 (core/engine_jax/tables.py) + replay "
               "bit-parity tests")
 
     def visit_Attribute(self, ctx, node):

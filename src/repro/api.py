@@ -38,7 +38,8 @@ from .core.hypertuner import (HyperTuningResult, MetaTuningResult,
                               meta_hypertune, score_hyperconfig)
 from .core.methodology import (DEFAULT_CUTOFF, AggregateReport, SpaceScorer,
                                make_scorer)
-from .core.parallel import CampaignExecutor, CampaignJournal
+from .core.parallel import (CampaignExecutor, CampaignJournal,
+                            in_process_backend)
 
 __all__ = ["Hub", "Tuner", "TuningRun", "describe_space",
            "hyperparam_space_stats", "lint"]
@@ -322,6 +323,8 @@ class Tuner:
 
     @property
     def executor(self) -> CampaignExecutor:
+        """The worker pool (campaigns on the ``"jax"`` engine keep their
+        device work in this process and leave it idle)."""
         if self._executor is None:
             self._executor = CampaignExecutor(self.workers, self.backend)
         return self._executor
@@ -401,7 +404,7 @@ class Tuner:
                          meta=res, fuse=res.fuse)
 
     def record(self, kernel: str, runner: str = "live",
-               device: str = "cpu_interpret",
+               device: str | None = None,
                problem: Mapping | None = None,
                strategy: str = "random_search",
                hyperparams: Mapping | None = None,
@@ -413,7 +416,15 @@ class Tuner:
         (Sec. III-C/D): strategy-sampled by default, exhaustive with
         ``bruteforce=True``; sharded across this tuner's workers, shards
         crash-safe and resumable. Returns the merged cache (saved to
-        ``out``) plus the best recorded configuration."""
+        ``out``) plus the best recorded configuration.
+
+        ``device`` names the device model of the ``costmodel`` and
+        ``surrogate`` runners (default ``tpu_v5e``). A ``live`` recording
+        is labelled with the device it runs on and its workers are threads
+        of this process, which holds that device. Kernels compiled for a
+        TPU time themselves by the host clock, so there the shards run one
+        after another: a kernel queued behind another worker's would count
+        the wait as its own time."""
         from .core import record as rec
         from .kernels import get_kernel
 
@@ -424,7 +435,16 @@ class Tuner:
             problem=dict(problem or {}), strategy=strategy,
             hyperparams=dict(hyperparams or {}), repeats=repeats,
             max_evals=max_evals, max_seconds=max_seconds, seed=self.seed)
-        out = out or os.path.join("recorded", f"{kernel}@{device}.json.gz")
+        if runner != "live":
+            executor = self.executor
+        elif spec.interpret:
+            executor = CampaignExecutor(
+                self.workers, in_process_backend(self.backend,
+                                                 "live recording"))
+        else:
+            executor = CampaignExecutor(1)
+        out = out or os.path.join("recorded",
+                                  f"{kernel}@{spec.device}.json.gz")
         prefix = out
         for ext in (".json.zst", ".json.gz", ".json"):
             if prefix.endswith(ext):
@@ -436,13 +456,20 @@ class Tuner:
                 else rec.record_shard_task)
         argtuples = [(w, n, prefix) for w in range(n)]
         measured = 0.0
-        for _, summary in self.executor.map(task, argtuples, shared=spec):
-            measured += summary["measured_seconds"]
-            if self.progress:
-                self.progress(
-                    f"worker {summary['worker']}: {summary['recorded']} "
-                    f"recorded (+{summary['resumed']} resumed) "
-                    f"-> {summary['path']}")
+        errors = []
+        try:
+            for _, summary in executor.map(task, argtuples, shared=spec):
+                measured += summary["measured_seconds"]
+                errors.append(summary["first_error"])
+                if self.progress:
+                    self.progress(
+                        f"worker {summary['worker']}: "
+                        f"{summary['recorded']} recorded "
+                        f"(+{summary['resumed']} resumed) "
+                        f"-> {summary['path']}")
+        finally:
+            if runner == "live":
+                executor.shutdown()
         space = rec.registry_space(kernel, dict(problem or {}))
         cache = rec.merge_shards(
             [rec.shard_path(prefix, w) for w in range(n)], space=space,
@@ -451,6 +478,12 @@ class Tuner:
         best_cfg = best_val = None
         ok = [(r.time_s, k) for k, r in cache.results.items()
               if r.status == "ok"]
+        if runner == "live" and cache.results and not ok:
+            # compile refusals are constraints of the space, but a kernel
+            # of which no config runs on this device is a fault
+            reason = next((e for e in errors if e), "see the shards")
+            raise ValueError(f"no config of {kernel} ran on {spec.device} "
+                             f"({len(cache.results)} tried): {reason}")
         if ok:
             best_val, key = min(ok)
             best_cfg = cache.space.as_dict(cache.space.config_from_id(key))

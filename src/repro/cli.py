@@ -16,8 +16,8 @@ One entry point for the paper's workflow, replacing the ad-hoc scripts in
              the strategies' hyperparameter grids: cartesian vs valid
              size, valid fraction, neighbor-degree distribution, compile
              time (the ``core.space`` compiled representation)
-  record     strategy-sample a registered Pallas kernel (live interpret
-             mode or cost model) across parallel workers and emit a
+  record     strategy-sample a registered Pallas kernel (live on the JAX
+             device, or a cost model) across parallel workers and emit a
              replayable T4 cache — producing the FAIR data the simulation
              mode consumes (Sec. III-C/D)
   bruteforce exhaustively record a registered kernel's whole valid space
@@ -82,10 +82,11 @@ def _add_space_args(p: argparse.ArgumentParser) -> None:
                    help="simulation engine: 'vectorized' resolves lookups "
                         "and scoring through columnar numpy arrays; "
                         "'scalar' is the per-evaluation reference path; "
-                        "'jax' replays row batches through the jitted "
-                        "device kernel (falls back to 'vectorized' when no "
-                        "jax backend is importable). Scores are "
-                        "bit-identical across all three (see "
+                        "'jax' runs the budget replay on the JAX device "
+                        "(whole campaigns per dispatch for GA, PSO, DE "
+                        "and random search), all of it in this process, "
+                        "which holds the device: --workers does not apply. "
+                        "Scores are bit-identical across all three (see "
                         "docs/performance.md)")
 
 
@@ -310,7 +311,7 @@ def _run_recording(args, bruteforce: bool) -> int:
     total = (cache.space.size if cache.space is not None
              else len(cache.results))
     print(f"{mode}: {len(cache.results)}/{total} configs recorded "
-          f"({n_ok} ok) for {args.kernel}@{args.device} "
+          f"({n_ok} ok) for {args.kernel}@{cache.device} "
           f"[{args.runner}] in {run.wall_seconds:.1f} s wall "
           f"({max(1, args.workers)} workers)")
     if run.best_config is not None:
@@ -704,15 +705,17 @@ def build_parser() -> argparse.ArgumentParser:
         pp.add_argument("--runner", choices=("live", "costmodel",
                                              "surrogate"),
                         default=("costmodel" if bruteforce else "live"),
-                        help="live = Pallas interpret mode on this host; "
-                             "costmodel = analytic device model; surrogate "
-                             "= deterministic roofline pricing "
-                             "(docs/scenarios.md)")
-        pp.add_argument("--device",
-                        default=("tpu_v5e" if bruteforce else "cpu_interpret"),
+                        help="live = the Pallas kernel timed on the JAX "
+                             "device (compiled on a TPU, interpret mode "
+                             "elsewhere); costmodel = analytic device "
+                             "model; surrogate = deterministic roofline "
+                             "pricing (docs/scenarios.md)")
+        pp.add_argument("--device", default=None,
                         help="device model for --runner costmodel/"
-                             "surrogate; a label recorded in the cache "
-                             "otherwise")
+                             "surrogate (default tpu_v5e); a live "
+                             "recording is labelled with the device it "
+                             "runs on, and a --device naming another one "
+                             "is an error")
         pp.add_argument("--problem", default=None, metavar="K=V,...",
                         help="problem-size overrides (e.g. m=256,n=256,"
                              "k=256); default: the kernel's smoke sizes")
@@ -736,7 +739,9 @@ def build_parser() -> argparse.ArgumentParser:
                              "Shards land next to it and survive crashes: "
                              "rerun the same command to resume.")
         pp.add_argument("--workers", type=int, default=1,
-                        help="parallel recording workers (one shard each)")
+                        help="parallel recording workers (one shard each; "
+                             "threads of this process for --runner live, "
+                             "which run one after another on a TPU)")
         pp.add_argument("--backend", choices=("auto", "thread", "process"),
                         default="auto")
         pp.add_argument("--seed", type=int, default=0)
@@ -891,8 +896,25 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+# JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is not
+# set: one fixed path inside the checkout, since the path is part of what a
+# later run must find again
+COMPILE_CACHE_DIR = os.path.normpath(
+    os.path.join(os.path.dirname(__file__), "..", "..", ".jax_cache"))
+
+
+def use_compile_cache() -> None:
+    """Point JAX's persistent compilation cache at ``COMPILE_CACHE_DIR``,
+    unless ``JAX_COMPILATION_CACHE_DIR`` is set (JAX then reads it
+    itself). Call before the first compilation."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+
+
 def main(argv: Sequence[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     try:
         return args.fn(args)
     except ValueError as e:

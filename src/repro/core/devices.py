@@ -2,17 +2,34 @@
 
 The paper's benchmark hub spans six GPUs (A100, A4000, A6000, MI250X, W6600,
 W7800) whose differing compute/bandwidth balances make kernel optima
-device-dependent. This container is CPU-only, so the hub here spans six
-*TPU-like device models* with the same kind of diversity: peak bf16 FLOP/s,
-HBM bandwidth, VMEM capacity, MXU tile, and noise level differ per device.
-The production target (v5e) is one of them.
+device-dependent. The hub here spans six *TPU-like device models* with the
+same kind of diversity: peak bf16 FLOP/s, HBM bandwidth, VMEM capacity, MXU
+tile, and noise level differ per device. The production target (v5e) is
+one of them.
 
 These constants drive the analytical kernel cost model (costmodel.py) that
 plays the role of hardware measurement when brute-forcing the hub dataset.
+Live recordings are measured on the device JAX runs on instead, and are
+labelled by ``live_device``.
 """
 from __future__ import annotations
 
 import dataclasses
+import re
+
+
+def live_device() -> tuple[str, bool]:
+    """``(label, interpret)`` for a live recording on this process's
+    default JAX device. On a TPU the Pallas kernels compile for the chip
+    and the label comes from its ``device_kind`` ("TPU v5 lite" ->
+    ``tpu_v5_lite``); elsewhere they run in interpret mode, labelled
+    ``<platform>_interpret``."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        label = re.sub(r"[^a-z0-9]+", "_", dev.device_kind.lower())
+        return label.strip("_"), False
+    return f"{dev.platform}_interpret", True
 
 
 @dataclasses.dataclass(frozen=True)

@@ -490,10 +490,7 @@ def drive_many(drivers: Sequence[SearchDriver],
         fused: list[SearchDriver] = []
         host_drivers = []
         for d in drivers:
-            reason = (engine_jax.fuse_reason(d)
-                      if engine_jax.engine_available() else
-                      "jax engine unavailable "
-                      f"({engine_jax.unavailable_reason()})")
+            reason = engine_jax.fuse_reason(d)
             if reason is None:
                 d.fuse = "device"
                 fused.append(d)

@@ -21,70 +21,16 @@ Two execution modes with two different parity contracts:
     against themselves, and distributions match the numpy strategies
     (docs/performance.md explains the contract).
 
-JAX is an optional dependency: everything here degrades cleanly. When jax
-(or a usable backend) is absent, ``engine_available()`` is False and a
-``SimulationRunner(engine="jax")`` transparently falls back to the numpy
-row path — safe precisely because replay-from-log is bit-identical either
-way. Float64 is enabled per-dispatch via ``jax.experimental.enable_x64``,
-so the engine does not depend on (or mutate) the process-global
-``JAX_ENABLE_X64`` setting.
+``engine="jax"`` always dispatches through JAX, on whatever platform JAX
+initialized; it never degrades to numpy. Float64 is enabled per dispatch
+via ``jax.enable_x64``, so the engine does not depend on (or mutate) the
+process-global ``JAX_ENABLE_X64`` setting.
 """
 from __future__ import annotations
 
-try:
-    import jax as _jax
-
-    HAVE_JAX = True
-    JAX_UNAVAILABLE_REASON = ""
-except Exception as _exc:  # pragma: no cover - exercised on minimal envs
-    HAVE_JAX = False
-    JAX_UNAVAILABLE_REASON = f"{type(_exc).__name__}: {_exc}"
-
-_BACKEND: "str | None | bool" = False  # False = not probed yet
-
-
-def backend_name() -> "str | None":
-    """Platform of the default jax backend (``"cpu"``/``"gpu"``/``"tpu"``),
-    or None when jax is missing or cannot initialize any device. Probed
-    once — a worker whose accelerator disappeared (process pools fork
-    without device handles) lands on the CPU backend or on None, never on
-    an exception."""
-    global _BACKEND
-    if _BACKEND is False:
-        if not HAVE_JAX:
-            _BACKEND = None
-        else:
-            try:
-                _BACKEND = _jax.devices()[0].platform
-            except Exception:  # pragma: no cover - no usable backend
-                _BACKEND = None
-    return _BACKEND
-
-
-def engine_available() -> bool:
-    """True when the jax engine can actually dispatch (import + backend)."""
-    return backend_name() is not None
-
-
-def unavailable_reason() -> str:
-    if not HAVE_JAX:
-        return JAX_UNAVAILABLE_REASON
-    if backend_name() is None:  # pragma: no cover - no usable backend
-        return "jax imported but no backend initialized"
-    return ""
-
-
-def require_jax() -> None:
-    if not engine_available():
-        raise RuntimeError(
-            f"the jax engine is unavailable ({unavailable_reason()}); "
-            f"use engine='numpy' or install jax")
-
-
-if HAVE_JAX:
-    from .campaign import FUSED_STRATEGIES, FusedRun  # noqa: F401
-    from .campaign import drive_fused, fuse_reason  # noqa: F401
-    from .replay import ReplayEngine, replay_many  # noqa: F401
-    from .strategies import FREE_RUN_STRATEGIES, free_run  # noqa: F401
-    from .tables import ReplayTables, SpaceTables  # noqa: F401
-    from .tables import replay_tables, space_tables  # noqa: F401
+from .campaign import FUSED_STRATEGIES, FusedRun  # noqa: F401
+from .campaign import drive_fused, fuse_reason  # noqa: F401
+from .replay import ReplayEngine, replay_many  # noqa: F401
+from .strategies import FREE_RUN_STRATEGIES, free_run  # noqa: F401
+from .tables import ReplayTables, SpaceTables  # noqa: F401
+from .tables import replay_tables, space_tables  # noqa: F401

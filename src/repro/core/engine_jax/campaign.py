@@ -37,13 +37,13 @@ from __future__ import annotations
 import numpy as np
 
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from ..cache import CachedResult
 from ..runner import INVALID, Observation, SimulationRunner
 from ..space import RowBatch
 from .replay import _budget_limits, _pad_len, _replay_vjit, first_occurrence
-from .tables import replay_tables
+from .tables import f64_bits, replay_tables
 
 # strategies whose ask/tell trajectory is host-replayable from values alone:
 # tell reads only ``observation.value`` (never status/config/result), and
@@ -78,14 +78,11 @@ def fuse_reason(driver) -> "str | None":
     point, and a GA/PSO/DE run with no budget cap never terminates — the
     sequential path at least surfaces progress while it spins.
     """
-    from . import engine_available, unavailable_reason
     strategy = driver.strategy
     name = getattr(strategy, "name", type(strategy).__name__)
     if name not in FUSED_STRATEGIES:
         return (f"strategy {name!r} is not array-native "
                 f"(trajectory not host-replayable from values alone)")
-    if not engine_available():
-        return f"jax engine unavailable ({unavailable_reason()})"
     runner = driver.runner
     if not isinstance(runner, SimulationRunner):
         return f"runner {type(runner).__name__} is not a SimulationRunner"
@@ -269,14 +266,15 @@ def _drive_group(runs: "list[FusedRun]", cols, compiled) -> int:
             out = _replay_vjit(
                 jnp.asarray(rows_m), jnp.asarray(fresh_m),
                 tables.col_of_row, tables.time_s, tables.charge_s,
-                jnp.float64(mean_charge), jnp.asarray(spent0),
-                jnp.asarray(evals0), jnp.asarray(max_s),
-                jnp.asarray(max_e))
+                jnp.asarray(f64_bits(mean_charge)),
+                jnp.asarray(f64_bits(spent0)), jnp.asarray(evals0),
+                jnp.asarray(f64_bits(max_s)), jnp.asarray(max_e))
+        # float64 columns come back as bit patterns (see replay.py)
         accept = np.asarray(out[0])
-        t_after = np.asarray(out[1])
-        value = np.asarray(out[2])
-        charge = np.asarray(out[3])
-        spent = np.asarray(out[4])
+        t_after = np.asarray(out[1]).view(np.float64)
+        value = np.asarray(out[2]).view(np.float64)
+        charge = np.asarray(out[3]).view(np.float64)
+        spent = np.asarray(out[4]).view(np.float64)
         evals = np.asarray(out[5])
         exhausted = np.asarray(out[6])
         survivors: list = []

@@ -11,6 +11,13 @@ charges are non-negative, so exhaustion is monotone — the per-step
 ``stopped`` flag of a naive transcription is redundant, and dropping it
 from the carry is worth ~15x on the CPU backend.
 
+Every float64 quantity lives on the device as its IEEE-754 bit pattern in
+an int64, and the additions are done in integer arithmetic
+(``f64_add_bits``). The TPU has no float64 unit: XLA splits a float64 into
+a pair of float32 (about 48 significant bits), so a native float64 scan
+there would round differently from the host. Integer arithmetic is exact
+on every backend; the host reinterprets the bits (``as_f64``).
+
 Within-batch first-occurrence dedup stays on the host (the same stable
 argsort as ``SimulationRunner._commit_rows_vectorized``): a device
 scatter-min over the whole batch costs more than the entire scan, and the
@@ -28,12 +35,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from ..budget import BudgetExhausted
 from ..cache import CachedResult
 from ..runner import Observation
-from .tables import ReplayTables, replay_tables
+from .tables import ReplayTables, as_f64, f64_bits, replay_tables
 
 INVALID = float("inf")
 _PAD_MIN = 8
@@ -43,6 +50,49 @@ _UNROLL = 8
 # unlimited-budget stand-ins (device scalars cannot be None)
 _NO_MAX_S = float("inf")
 _NO_MAX_E = 2 ** 62
+_F64_ONE = 1 << 52          # the implicit leading bit of a binary64 significand
+_F64_INF = 0x7FF0 << 48     # bit pattern of +inf
+
+
+def _to_host(out) -> tuple:
+    """A replay dispatch's outputs ``(accept, t_after, value, charge,
+    spent, evals, exhausted)`` as host arrays, the float64 columns
+    reinterpreted from their bit patterns."""
+    accept, t_after, value, charge, spent, evals, exhausted = (
+        np.asarray(o) for o in out)
+    return (accept, as_f64(t_after), as_f64(value), as_f64(charge),
+            as_f64(spent), evals, exhausted)
+
+
+def f64_add_bits(a, b):
+    """``a + b`` in IEEE-754 binary64, round half to even, computed on the
+    int64 bit patterns of two non-negative finite doubles (sums past the
+    largest double saturate to +inf). Non-negative doubles order like their
+    bit patterns, so the caller compares them as integers too.
+
+    Operands are aligned with three extra bits (guard, round, sticky),
+    added, renormalized by at most one place, and rounded. Subnormals need
+    no special case: their exponent field is 0 but they scale like
+    exponent 1, and a significand that carries into bit 52 re-encodes as
+    the next exponent by plain integer addition."""
+    hi = jnp.maximum(a, b)
+    lo = jnp.minimum(a, b)
+    e_hi = hi >> 52
+    e_lo = lo >> 52
+    m_hi = ((hi & (_F64_ONE - 1)) | jnp.where(e_hi > 0, _F64_ONE, 0)) << 3
+    m_lo = ((lo & (_F64_ONE - 1)) | jnp.where(e_lo > 0, _F64_ONE, 0)) << 3
+    e_hi = jnp.maximum(e_hi, 1)
+    # m_lo < 2**56: a shift of 60 already clears it into the sticky bit
+    shift = jnp.minimum(e_hi - jnp.maximum(e_lo, 1), 60)
+    sticky = (m_lo & ((jnp.int64(1) << shift) - 1)) != 0
+    s = m_hi + ((m_lo >> shift) | sticky.astype(jnp.int64))
+    carry = s >> 56                       # 0 or 1: the sum grew a bit
+    s = (s >> carry) | (s & carry)        # the bit shifted out stays sticky
+    grs = s & 7
+    m = s >> 3
+    up = (grs > 4) | ((grs == 4) & ((m & 1) == 1))
+    bits = ((e_hi + carry - 1) << 52) + m + up.astype(jnp.int64)
+    return jnp.minimum(bits, _F64_INF)
 
 
 def _pad_len(n: int) -> int:
@@ -68,16 +118,18 @@ def budget_scan(fresh, charge, spent0, evals0, max_s, max_e):
 
     Bit-for-bit the scalar commit loop: a fresh evaluation commits iff
     ``spent < max_s and evals < max_e`` *before* the eval; committed
-    charges accumulate left-to-right in float64. Returns the accept mask,
-    the after-commit spend per entry (the trace time column), the final
-    ``(spent, evals)``, and whether any fresh evaluation was rejected
-    (the ``BudgetExhausted`` point of the equivalent ``run`` loop)."""
+    charges accumulate left-to-right in float64. ``charge``, ``spent0``
+    and ``max_s`` are float64 bit patterns (``f64_bits``). Returns the
+    accept mask, the after-commit spend bits per entry (the trace time
+    column), the final ``(spent, evals)``, and whether any fresh
+    evaluation was rejected (the ``BudgetExhausted`` point of the
+    equivalent ``run`` loop)."""
 
     def body(carry, x):
         spent, evals = carry
         f, c = x
         commit = f & (spent < max_s) & (evals < max_e)
-        spent2 = jnp.where(commit, spent + c, spent)
+        spent2 = jnp.where(commit, f64_add_bits(spent, c), spent)
         return (spent2, evals + commit.astype(evals.dtype)), (commit, spent2)
 
     (spent, evals), (accept, t_after) = jax.lax.scan(
@@ -88,13 +140,14 @@ def budget_scan(fresh, charge, spent0, evals0, max_s, max_e):
 
 def _replay_segment(rows, fresh, col_of_row, time_s, charge_s, mean_charge,
                     spent0, evals0, max_s, max_e):
-    """One run's segment commit: gathers + ``budget_scan``. Rows absent
-    from the recorded set (col < 0) take the imputed-miss path — value inf,
-    mean charge — like the keyed/scalar engines."""
+    """One run's segment commit: gathers + ``budget_scan``, every float64
+    as its bit pattern. Rows absent from the recorded set (col < 0) take
+    the imputed-miss path — value inf, mean charge — like the keyed/scalar
+    engines."""
     col = col_of_row[rows]
     miss = col < 0
     safe = jnp.clip(col, 0)
-    value = jnp.where(miss, jnp.inf, time_s[safe])
+    value = jnp.where(miss, _F64_INF, time_s[safe])
     charge = jnp.where(miss, mean_charge, charge_s[safe])
     accept, t_after, spent, evals, exhausted = budget_scan(
         fresh, charge, spent0, evals0, max_s, max_e)
@@ -160,12 +213,12 @@ class ReplayEngine:
             out = _replay_jit(
                 jnp.asarray(rows_p), jnp.asarray(fresh_p),
                 tables.col_of_row, tables.time_s, tables.charge_s,
-                jnp.float64(mean_charge),
-                jnp.float64(budget.spent_seconds),
+                jnp.asarray(f64_bits(mean_charge)),
+                jnp.asarray(f64_bits(budget.spent_seconds)),
                 jnp.int64(budget.spent_evals),
-                jnp.float64(max_s), jnp.int64(max_e))
+                jnp.asarray(f64_bits(max_s)), jnp.int64(max_e))
             accept, t_after, value, charge, spent, evals, exhausted = (
-                np.asarray(o) for o in out)
+                _to_host(out))
         # ------------------------------------------------- host-side commit
         # (mirrors _commit_rows_vectorized: fresh commits build
         # Observations, revisits gather from the row-indexed object array)
@@ -221,7 +274,7 @@ def replay_many(cols, compiled, rows_matrix, *, seen=None,
     vmapped dispatch (the workload behind the ``jax_replay`` bench).
 
     ``rows_matrix`` is (R, N) int rows; per-run scalars broadcast from
-    Python numbers or arrive as (R,) arrays. Returns device arrays
+    Python numbers or arrive as (R,) arrays. Returns host arrays
     ``(accept, t_after, value, charge, spent, evals, exhausted)`` — each
     run's slice bit-identical to what a ``SimulationRunner`` replaying the
     same segment would commit (tests/test_engine_jax.py pins this). Rows
@@ -243,14 +296,15 @@ def replay_many(cols, compiled, rows_matrix, *, seen=None,
         def per_run(x, default, dtype):
             if x is None:
                 x = default
-            arr = jnp.asarray(x, dtype=dtype)
-            return jnp.broadcast_to(arr, (runs,))
+            arr = (f64_bits(x) if dtype == np.float64
+                   else np.asarray(x, dtype=dtype))
+            return jnp.broadcast_to(jnp.asarray(arr), (runs,))
 
         out = _replay_vjit(
             rows_d, fresh, tables.col_of_row, tables.time_s, tables.charge_s,
-            jnp.float64(mean_charge),
-            per_run(spent0, 0.0, jnp.float64),
-            per_run(evals0, 0, jnp.int64),
-            per_run(max_seconds, _NO_MAX_S, jnp.float64),
-            per_run(max_evals, _NO_MAX_E, jnp.int64))
-    return out
+            jnp.asarray(f64_bits(mean_charge)),
+            per_run(spent0, 0.0, np.float64),
+            per_run(evals0, 0, np.int64),
+            per_run(max_seconds, _NO_MAX_S, np.float64),
+            per_run(max_evals, _NO_MAX_E, np.int64))
+        return _to_host(out)

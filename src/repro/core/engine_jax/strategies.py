@@ -20,11 +20,15 @@ device-friendly:
     gene set).
 
 Everything on the budget side *is* exact: generations charge through the
-same ``budget_scan`` as replay-from-log (left-to-right float64, fresh-only,
-pre-eval exhaustion check), revisits are free via a per-run ``seen`` bitmap,
-and a run freezes at the generation where the numpy driver would have
-caught ``BudgetExhausted``. Pinned seeds reproduce bit-for-bit against
-themselves on a given backend.
+same ``budget_scan`` as replay-from-log (left-to-right float64 on bit
+patterns, fresh-only, pre-eval exhaustion check), revisits are free via a
+per-run ``seen`` bitmap, and a run freezes at the generation where the
+numpy driver would have caught ``BudgetExhausted``. Pinned seeds
+reproduce bit-for-bit against themselves on a given backend. The best
+value is tracked as an int64 key of its bit pattern (``_order_key``), so
+``best_value`` and ``curve_best`` are recorded values exactly; only the
+fitness the strategies steer by is native float64, which a TPU holds as a
+pair of float32.
 """
 from __future__ import annotations
 
@@ -33,15 +37,32 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from ..strategies.base import FAILURE_FITNESS
-from .replay import _NO_MAX_E, _NO_MAX_S, budget_scan
-from .tables import replay_tables, space_tables
+from .replay import _F64_INF, _NO_MAX_E, _NO_MAX_S, budget_scan
+from .tables import as_f64, f64_bits, replay_tables, space_tables
+
+
+_LOW63 = 0x7FFF_FFFF_FFFF_FFFF
+_EXP = 0x7FF << 52               # exponent bits: all set for inf and NaN
+
+
+def _order_key(bits):
+    """float64 bit patterns (int64) -> int64 keys that order as the values
+    do (NaN aside). Negative values have their magnitude bits flipped; the
+    map is its own inverse."""
+    return jnp.where(bits < 0, bits ^ _LOW63, bits)
 
 
 def _rand_rows(key, n_valid: int, shape) -> jnp.ndarray:
     return jax.random.randint(key, shape, 0, n_valid)
+
+
+def _flat(k, strides):
+    """Flat Cartesian index of value-index rows ``k`` (int64). A sum of
+    products rather than ``k @ strides``: a TPU has no int64 dot."""
+    return jnp.sum(k.astype(jnp.int64) * strides, axis=-1, dtype=jnp.int64)
 
 
 def _decode(x, st, key):
@@ -49,7 +70,7 @@ def _decode(x, st, key):
     positions restart at a uniform random valid row (device-side stand-in
     for the BFS repair tables)."""
     k = jnp.clip(jnp.rint(x), 0.0, st["x_hi"]).astype(jnp.int64)
-    flat = k @ st["strides"]
+    flat = _flat(k, st["strides"])
     rows = st["row_of_flat"][flat].astype(jnp.int32)
     rnd = _rand_rows(key, st["n_valid"], rows.shape).astype(jnp.int32)
     return jnp.where(rows < 0, rnd, rows)
@@ -109,7 +130,7 @@ class _GA:
         need = state["it"] == 0
         init_pop = st["vidx"][_rand_rows(key, st["n_valid"], (P,))]
         pop = jnp.where(need, init_pop, state["pop"])
-        rows = st["row_of_flat"][pop.astype(jnp.int64) @ st["strides"]]
+        rows = st["row_of_flat"][_flat(pop, st["strides"])]
         return rows.astype(jnp.int32), {**state, "pop": pop}
 
     @staticmethod
@@ -134,7 +155,7 @@ class _GA:
                           * cards[None, :]).astype(jnp.int32)
         children = jnp.where(mut, draws, children)
         # repair: invalid offspring restart at a random valid genome
-        flat = children.astype(jnp.int64) @ st["strides"]
+        flat = _flat(children, st["strides"])
         bad = st["row_of_flat"][flat] < 0
         rescue = st["vidx"][_rand_rows(kr, st["n_valid"], (P - 1,))]
         children = jnp.where(bad[:, None], rescue, children)
@@ -303,8 +324,8 @@ def _free_run_jit(impl, P, G, hp_key, cards, keys, col_of_row, time_s,
         k_loop = key
         state0 = impl.init(st, P, hp)
         carry0 = (state0, k_loop, jnp.zeros(n_valid, bool),
-                  jnp.float64(0.0), jnp.int64(0),
-                  jnp.float64(jnp.inf), jnp.int32(-1), jnp.int64(0),
+                  jnp.int64(0), jnp.int64(0),  # spend: bits of 0.0
+                  jnp.int64(_F64_INF), jnp.int32(-1), jnp.int64(0),
                   jnp.bool_(False))
 
         def gen(carry, _):
@@ -320,18 +341,20 @@ def _free_run_jit(impl, P, G, hp_key, cards, keys, col_of_row, time_s,
             col = col_of_row[rows]
             miss = col < 0
             safe = jnp.clip(col, 0)
-            value = jnp.where(miss, jnp.inf, time_s[safe])
+            bits = jnp.where(miss, _F64_INF, time_s[safe])
+            finite = (bits & _EXP) != _EXP
             charge = jnp.where(miss, mean_charge, charge_s[safe])
             accept, _t, spent2, evals2, exh = budget_scan(
                 fresh, charge, spent, evals, max_s, max_e)
             seen2 = seen.at[rows].max(accept)
             fresh_n2 = fresh_n + jnp.sum(accept)
-            okv = jnp.where(accept & jnp.isfinite(value), value, jnp.inf)
+            okv = jnp.where(accept & finite, _order_key(bits), _F64_INF)
             j = jnp.argmin(okv)
             better = okv[j] < best_v
             best_v2 = jnp.where(better, okv[j], best_v)
             best_r2 = jnp.where(better, rows[j], best_r).astype(jnp.int32)
-            fitness = jnp.where(jnp.isfinite(value), value, FAILURE_FITNESS)
+            value = jax.lax.bitcast_convert_type(bits, jnp.float64)
+            fitness = jnp.where(finite, value, FAILURE_FITNESS)
             state_b = impl.tell(state_a, rows, fitness, k_tell, st, P, hp)
             # once exhausted the numpy driver stops stepping the strategy;
             # budget/seen/best are already monotone-frozen (no accepts can
@@ -390,7 +413,11 @@ def free_run(cache, strategy: str = "genetic_algorithm", *, runs: int = 32,
         out = _free_run_jit(impl, P, G, hp_key, st.cards, keys,
                             rt.col_of_row, rt.time_s, rt.charge_s,
                             st.vidx, st.row_of_flat, st.strides, st.x_hi,
-                            jnp.float64(mean_charge), jnp.float64(max_s),
-                            jnp.int64(max_e))
+                            jnp.asarray(f64_bits(mean_charge)),
+                            jnp.asarray(f64_bits(max_s)), jnp.int64(max_e))
         out = {k: np.asarray(v) for k, v in out.items()}
+    for k in ("spent_seconds", "curve_spent"):
+        out[k] = as_f64(out[k])
+    for k in ("best_value", "curve_best"):  # keys -> bits -> values
+        out[k] = as_f64(np.where(out[k] < 0, out[k] ^ _LOW63, out[k]))
     return out

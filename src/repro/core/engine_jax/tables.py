@@ -8,20 +8,34 @@ the memo in ``__getstate__``/``__reduce__`` paths, so a process-pool worker
 rebuilds its tables against whatever backend it actually has
 (tests/test_parallel.py pins this).
 
-All float tables are created under ``enable_x64`` — jax's default float32
-would silently truncate the cache's float64 charge/time columns and break
-the bit-parity contract (the ``JAX_ENABLE_X64`` CI row guards the other
-direction: the suite must also pass when x64 is on globally).
+The cache's float64 charge/time columns are held as their int64 bit
+patterns (``f64_bits``), and every table is created under
+``jax.enable_x64`` — jax's default 32-bit types would silently truncate
+them and break the bit-parity contract (the ``JAX_ENABLE_X64`` CI row
+guards the other direction: the suite must also pass when x64 is on
+globally).
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+import numpy as np
+from jax import enable_x64
+
+
+def f64_bits(x) -> np.ndarray:
+    """Host float64 value(s) -> their IEEE-754 bit patterns as int64."""
+    return np.asarray(x, dtype=np.float64).view(np.int64)
+
+
+def as_f64(bits) -> np.ndarray:
+    """Device or host int64 bit patterns -> host float64 values."""
+    return np.asarray(bits).view(np.float64)
 
 
 class ReplayTables:
     """Replay-from-log tables for one (CacheColumns, CompiledSpace) pair:
-    the space-row -> cache-row bridge plus the value/charge columns."""
+    the space-row -> cache-row bridge plus the value/charge columns (as
+    float64 bit patterns)."""
 
     __slots__ = ("n_valid", "col_of_row", "time_s", "charge_s", "has_miss")
 
@@ -29,8 +43,8 @@ class ReplayTables:
         col_map = cols.rows_for_space(compiled)
         with enable_x64():
             self.col_of_row = jnp.asarray(col_map, dtype=jnp.int32)
-            self.time_s = jnp.asarray(cols.time_s)      # float64
-            self.charge_s = jnp.asarray(cols.charge_s)  # float64
+            self.time_s = jnp.asarray(f64_bits(cols.time_s))
+            self.charge_s = jnp.asarray(f64_bits(cols.charge_s))
         self.n_valid = int(compiled.n_valid)
         self.has_miss = bool((col_map < 0).any()) if len(col_map) else False
 

@@ -35,7 +35,8 @@ from typing import Callable, Mapping, Sequence
 from .budget import Budget
 from .cache import CachedResult, CacheFile
 from .driver import SearchDriver
-from .methodology import AggregateReport, SpaceScorer, evaluate_strategy
+from .methodology import (AggregateReport, SpaceScorer, evaluate_strategy,
+                          host_executor)
 from .parallel import (CampaignExecutor, CampaignJournal, StrategyFactory,
                        campaign_header, report_from_json, report_to_json,
                        score_hyperconfig_task)
@@ -130,7 +131,8 @@ def exhaustive_hypertune(strategy_name: str, scorers: Sequence[SpaceScorer],
 
     ``executor`` fans configurations out over a worker pool; results are
     assembled in grid-enumeration order, so parallel campaigns are
-    bit-identical to serial ones (Sec. III-C determinism). ``journal``
+    bit-identical to serial ones (Sec. III-C determinism; see
+    ``methodology.host_executor`` for the ``"jax"`` engine). ``journal``
     checkpoints every completed configuration to JSONL; an interrupted
     campaign restarted with the same journal resumes where it left off,
     re-scoring nothing."""
@@ -157,7 +159,7 @@ def exhaustive_hypertune(strategy_name: str, scorers: Sequence[SpaceScorer],
                      f"{journal.path}")
     pending = [(i, hp) for i, hp in enumerate(hp_list) if ids[i] not in done]
     n_done = len(done)
-    executor = executor or CampaignExecutor()
+    executor = host_executor(executor, scorers) or CampaignExecutor()
     tasks = [(strategy_name, hp, repeats, seed) for _, hp in pending]
     for t_idx, report in executor.map(score_hyperconfig_task, tasks,
                                       shared=tuple(scorers)):

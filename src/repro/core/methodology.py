@@ -446,18 +446,12 @@ def run_repeats_device(scorer: SpaceScorer,
 
     Returns ``None`` — after a one-time ``FuseFallbackNotice`` — when the
     grid is not device-fusable (strategy outside the array-native
-    allowlist, no jax backend, empty cache); the caller then takes the
-    host drive.
+    allowlist, empty cache); the caller then takes the host drive.
     """
     from . import engine_jax
     from .driver import SearchDriver, warn_fuse_fallback
     probe = make_strategy()
     name = getattr(probe, "name", type(probe).__name__)
-    if not engine_jax.engine_available():
-        warn_fuse_fallback(
-            name, "jax engine unavailable "
-            f"({engine_jax.unavailable_reason()})", "host")
-        return None
     if name not in engine_jax.FUSED_STRATEGIES:
         warn_fuse_fallback(
             name, f"strategy {name!r} is not array-native "
@@ -496,6 +490,15 @@ def _repeat_cell(ctx: tuple, si: int, r: int) -> RepeatResult:
                       baselines[si])
 
 
+def host_executor(executor, scorers: Sequence[SpaceScorer]):
+    """``executor``, or None when a scorer is on the ``"jax"`` engine: the
+    device work of such a campaign stays in this process, which holds the
+    chip, whatever the worker count."""
+    if any(s.engine == "jax" for s in scorers):
+        return None
+    return executor
+
+
 def evaluate_strategy(make_strategy: Callable[[], Strategy],
                       scorers: Sequence[SpaceScorer],
                       repeats: int = 25,
@@ -508,7 +511,8 @@ def evaluate_strategy(make_strategy: Callable[[], Strategy],
 
     ``executor``: optional ``core.parallel.CampaignExecutor``; the
     (space × repeat) grid is fanned out and reduced in fixed space-major
-    order, so the aggregate is bit-identical to the serial loop.
+    order, so the aggregate is bit-identical to the serial loop (see
+    ``host_executor`` for grids on the ``"jax"`` engine).
 
     ``drive`` selects how the in-process grid executes: ``"device"``
     drives each space's repeats as one device-resident fused campaign
@@ -531,6 +535,7 @@ def evaluate_strategy(make_strategy: Callable[[], Strategy],
     cells_idx = [(si, r) for si in range(len(scorers)) for r in range(repeats)]
     cells: list[RepeatResult | None] = [None] * len(cells_idx)
     modes: list[str] = []
+    executor = host_executor(executor, scorers)
     if executor is not None and executor.parallel:
         ctx = (tuple(scorers), make_strategy, seed, times, baselines)
         # chunk the (space × repeat) grid: vectorized cells are cheap, so

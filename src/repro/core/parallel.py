@@ -113,6 +113,20 @@ def _run_chunk_global(fn: Callable, argtuples: Sequence[tuple]) -> list:
 
 
 # ---------------------------------------------------------------- executor
+def in_process_backend(backend: str, what: str) -> str:
+    """The pool backend for tasks that run JAX on the device.
+
+    One process at a time may hold the chip, and a forked child of a
+    process that touched JAX would need it too, so such tasks run on
+    threads of this process: ``"auto"`` resolves to ``"thread"``, and an
+    explicit ``"process"`` is refused."""
+    if backend == "process":
+        raise ValueError(
+            f"{what} runs JAX in this process, which holds the device; "
+            f"worker processes cannot share it — use --backend thread")
+    return "thread" if backend == "auto" else backend
+
+
 class CampaignExecutor:
     """Deterministic worker pool for campaign tasks (paper Sec. III-C/E).
 

@@ -34,7 +34,7 @@ from typing import Mapping, Sequence
 from .budget import Budget, BudgetExhausted
 from .cache import (CachedResult, CacheFile, membership_space,
                     result_from_json, result_to_json)
-from .devices import DEVICES_BY_NAME
+from .devices import DEVICES_BY_NAME, live_device
 from .parallel import CampaignJournal
 from .runner import CostModelRunner, LiveRunner, Observation, Runner
 from .searchspace import SearchSpace
@@ -236,14 +236,17 @@ def merge_shards(paths: Sequence[str], space: SearchSpace | None = None,
 @dataclasses.dataclass(frozen=True)
 class RecordSpec:
     """Picklable description of one recording campaign: everything a worker
-    process needs to rebuild the space and runner from the kernel registry
-    and write its shard. ``problem`` overrides the kernel's smoke problem
-    sizes; ``device`` selects the cost model's device when
-    ``runner == "costmodel"`` and is a label otherwise."""
+    needs to rebuild the space and runner from the kernel registry and
+    write its shard. ``problem`` overrides the kernel's smoke problem
+    sizes; ``device`` selects the device model of the ``costmodel`` and
+    ``surrogate`` runners. A ``live`` recording is labelled with the
+    device it runs on (``devices.live_device``): ``create`` fills in the
+    label and ``interpret`` (False when the kernels compile for a TPU) and
+    refuses a ``device`` that names another one."""
 
     kernel: str
-    runner: str = "live"            # "live" (Pallas interpret) | "costmodel"
-    device: str = "cpu_interpret"
+    runner: str = "live"            # "live" | "costmodel" | "surrogate"
+    device: str = "tpu_v5e"
     problem: tuple = ()             # sorted ((key, value), ...)
     strategy: str = "random_search"
     hyperparams: tuple = ()         # sorted ((key, value), ...)
@@ -251,9 +254,19 @@ class RecordSpec:
     max_evals: int | None = 64      # per-worker fresh-eval budget
     max_seconds: float | None = None
     seed: int = 0
+    interpret: bool | None = None   # live only; None: the platform's
 
     @staticmethod
     def create(kernel: str, **kw) -> "RecordSpec":
+        if kw.get("runner", "live") == "live":
+            label, interpret = live_device()
+            if kw.get("device") not in (None, label):
+                raise ValueError(
+                    f"live recording runs on {label!r}, not on "
+                    f"{kw['device']!r}; omit --device for live recordings")
+            kw["device"], kw["interpret"] = label, interpret
+        elif kw.get("device") is None:
+            kw.pop("device", None)
         kw["problem"] = tuple(sorted(dict(kw.get("problem") or {}).items()))
         kw["hyperparams"] = tuple(
             sorted(dict(kw.get("hyperparams") or {}).items()))
@@ -275,7 +288,7 @@ class RecordSpec:
     def make_runner(self, space: SearchSpace, budget: Budget) -> Runner:
         if self.runner == "live":
             spec = self.kernel_spec()
-            fn = spec.make_live(self.problem_dict)
+            fn = spec.make_live(self.problem_dict, self.interpret)
             return LiveRunner(space, fn, budget, repeats=self.repeats)
         if self.runner == "costmodel":
             try:
@@ -346,7 +359,8 @@ def record_shard_task(spec: RecordSpec, worker: int, n_workers: int,
     strategy.run(space, rec, rng)
     return {"worker": worker, "path": shard.path, "resumed": len(existing),
             "recorded": rec.recorded,
-            "measured_seconds": budget.spent_seconds}
+            "measured_seconds": budget.spent_seconds,
+            "first_error": getattr(runner, "first_error", None)}
 
 
 def bruteforce_shard_task(spec: RecordSpec, worker: int, n_workers: int,
@@ -369,4 +383,5 @@ def bruteforce_shard_task(spec: RecordSpec, worker: int, n_workers: int,
         pass  # partial shards are still mergeable/replayable
     return {"worker": worker, "path": shard.path, "resumed": len(existing),
             "recorded": rec.recorded,
-            "measured_seconds": budget.spent_seconds}
+            "measured_seconds": budget.spent_seconds,
+            "first_error": getattr(runner, "first_error", None)}

@@ -211,10 +211,8 @@ class SimulationRunner(Runner):
     (alias ``"vectorized"``; == ``columnar=True``), ``"scalar"``
     (== ``columnar=False``), or ``"jax"`` — the jitted device path of
     ``core.engine_jax``, whose replay-from-log commits are bit-identical to
-    the numpy engine (tests/test_engine_jax.py). When jax or a usable
-    backend is missing, ``"jax"`` degrades to the numpy path transparently
-    — safe precisely because the two are bit-identical, so a process-pool
-    worker without an accelerator produces the same campaign.
+    the numpy engine (tests/test_engine_jax.py). ``"jax"`` dispatches on
+    whatever platform JAX initialized and never degrades to numpy.
     """
 
     ENGINES = ("numpy", "scalar", "jax")
@@ -232,25 +230,18 @@ class SimulationRunner(Runner):
         self.cache = cache
         self.columnar = engine != "scalar"
         self.engine = engine
-        self._jax_eng: object = None  # lazy ReplayEngine / False once probed
+        self._jax_eng = None  # lazy engine_jax.ReplayEngine
 
     def _jax_engine(self):
-        """The bound ``engine_jax.ReplayEngine``, or None when jax cannot
-        dispatch (import failure, no backend) — callers then fall through
-        to the bit-identical numpy path."""
-        eng = self._jax_eng
-        if eng is None:
+        """The bound ``engine_jax.ReplayEngine`` (created on first use)."""
+        if self._jax_eng is None:
             from . import engine_jax
-            eng = self._jax_eng = (engine_jax.ReplayEngine(self)
-                                   if engine_jax.engine_available()
-                                   else False)
-        return eng or None
+            self._jax_eng = engine_jax.ReplayEngine(self)
+        return self._jax_eng
 
     def __getstate__(self) -> dict:
-        """Drop the probed engine handle: it captures whether *this*
-        process can dispatch jax (and, once bound, a jax-importing
-        ``ReplayEngine``), so a pickled runner must re-probe in the
-        receiving process — which may have a different backend."""
+        """Drop the bound engine: it holds device tables of *this*
+        process, so a pickled runner rebinds in the receiving one."""
         return {**self.__dict__, "_jax_eng": None}
 
     def _evaluate(self, config: Config) -> CachedResult:
@@ -333,12 +324,10 @@ class SimulationRunner(Runner):
         if n == 0:
             return []
         if self.engine == "jax":
-            eng = self._jax_engine()
-            if eng is not None:
-                # every batch with a fresh row dispatches on the device
-                # (single rows included — uniform coverage for the parity
-                # suite); fully-memoized batches short-circuit inside
-                return eng.commit_rows(rows)
+            # every batch with a fresh row dispatches on the device (single
+            # rows included — uniform coverage for the parity suite);
+            # fully-memoized batches short-circuit inside
+            return self._jax_engine().commit_rows(rows)
         if n == 1:
             # the single-move shape (simulated annealing, basin hopping,
             # the thread bridge): skip every batch prologue
@@ -715,13 +704,16 @@ class CostModelRunner(Runner):
 
 
 class LiveRunner(Runner):
-    """Times ``fn(config_dict)``; exceptions are runtime failures."""
+    """Times ``fn(config_dict)``; exceptions are runtime failures (a
+    compiler's refusal among them), the first of which is kept in
+    ``first_error``."""
 
     def __init__(self, space: SearchSpace, fn: Callable, budget: Budget,
                  repeats: int = 3):
         super().__init__(space, budget)
         self.fn = fn
         self.repeats = repeats
+        self.first_error: str | None = None
 
     def _evaluate(self, config: Config) -> CachedResult:
         d = self.space.as_dict(config)
@@ -736,7 +728,9 @@ class LiveRunner(Runner):
                 times.append(time.perf_counter() - t1)
             return CachedResult("ok", sum(times) / len(times), tuple(times),
                                 compile_s)
-        except Exception:
+        except Exception as e:
+            if self.first_error is None:
+                self.first_error = f"{type(e).__name__}: {e}".split("\n")[0]
             # a failed compile/run still cost the measured wall time
             return CachedResult("error", INVALID, (),
                                 time.perf_counter() - t0)

@@ -6,7 +6,8 @@ attention, Mamba2 SSD). Each module provides: the ``pl.pallas_call`` kernel,
 a jit'd wrapper, a pure-jnp oracle (``*_ref``), a tunable ``space()``, an
 analytic ``workload()`` for the cost model, and a recording contract
 (``SMOKE_PROBLEM`` + ``make_live``) that turns the kernel into a live
-interpret-mode objective the recorder (``core.record``) can measure.
+objective the recorder (``core.record``) can measure: compiled for the chip
+on a TPU, in interpret mode elsewhere.
 
 ``KERNELS``/``get_kernel`` is the registry the record→merge→replay pipeline
 resolves kernels through: every registered kernel is a simulation scenario —
@@ -30,6 +31,7 @@ from types import ModuleType
 from typing import Callable, Mapping
 
 from ..core.costmodel import KernelWorkload
+from ..core.devices import live_device
 from ..core.searchspace import SearchSpace
 from . import (convolution, dedispersion, flash_attention, gemm, hotspot,
                ssd)
@@ -83,11 +85,16 @@ class KernelSpec:
         p = self.problem(problem)
         return self.module.workload(**_accepted(self.module.workload, p))
 
-    def make_live(self, problem: Mapping | None = None) -> Callable:
-        """Interpret-mode ``fn(config_dict)`` over fixed inputs, for a
-        ``LiveRunner``. Built inside the worker that uses it (the closure
-        holds jax arrays and is not picklable)."""
-        return self.module.make_live(self.problem(problem))
+    def make_live(self, problem: Mapping | None = None,
+                  interpret: bool | None = None) -> Callable:
+        """``fn(config_dict)`` over fixed inputs, for a ``LiveRunner``.
+        ``interpret`` defaults to the platform's (``devices.live_device``):
+        compiled on a TPU, interpret mode elsewhere. Built inside the
+        worker that uses it (the closure holds jax arrays and is not
+        picklable)."""
+        if interpret is None:
+            _, interpret = live_device()
+        return self.module.make_live(self.problem(problem), interpret)
 
 
 KERNELS: dict[str, KernelSpec] = {

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 from ..core.costmodel import KernelWorkload, alignment_eff, dma_eff
 from ..core.devices import DeviceModel
 from ..core.searchspace import SearchSpace
@@ -88,7 +86,7 @@ def conv2d(x: jax.Array, f: jax.Array, *, strip_h: int = 64,
         ],
         out_specs=pl.BlockSpec((1, strip_h, block_w), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_i * n_j, strip_h, block_w), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(patches, f)
@@ -110,7 +108,7 @@ def conv2d_ref(x: jax.Array, f: jax.Array, **_unused) -> jax.Array:
 
 
 # ----------------------------------------------------------- live recording
-def make_live(problem: Mapping | None = None):
+def make_live(problem: Mapping | None, interpret: bool):
     """Recorder callable: same-padded conv on a fixed image/filter; the
     unroll/vector/accumulator tunables are cost-model-only."""
     p = {**SMOKE_PROBLEM, **(problem or {})}
@@ -121,7 +119,7 @@ def make_live(problem: Mapping | None = None):
 
     def fn(conf: Mapping) -> None:
         out = conv2d(x, f, strip_h=conf["strip_h"], block_w=conf["block_w"],
-                     interpret=True)
+                     interpret=interpret)
         jax.block_until_ready(out)
 
     return fn

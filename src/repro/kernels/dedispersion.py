@@ -3,9 +3,11 @@
 out[dm, t] = Σ_c x[c, t + delay[c, dm]] — a bandwidth-bound gather-reduce.
 GPU implementations tune thread tiles over (dm, time) and channel chunking;
 the TPU adaptation tiles (dm, time) over the grid with the channel loop
-inside the kernel, using per-(channel, dm-tile) dynamic slices of a
-VMEM-resident channel block. Delay table is precomputed (as real pipelines
-do) and passed as scalar-prefetch-style operand.
+inside the kernel: each (channel, dm) pair rotates its VMEM-resident
+channel row by the delay (a lane rotation — Mosaic lowers no unaligned
+dynamic lane slice) and accumulates the leading ``block_t`` lanes. The
+delay table is precomputed (as real pipelines do) and scalar-prefetched
+into SMEM.
 
 Tunables: block_dm, block_t (output tile), chan_chunk (channels per inner
 accumulation round), delay layout.
@@ -19,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ._compat import CompilerParams
 
 from ..core.costmodel import KernelWorkload, alignment_eff, dma_eff
 from ..core.devices import DeviceModel
@@ -47,24 +47,27 @@ def make_delays(nchan: int = HUB_NCHAN, ndm: int = HUB_NDM,
 
 
 # ----------------------------------------------------------------- kernel
-def _dedisp_kernel(delay_ref, x_ref, out_ref, *, nchan: int, block_dm: int,
-                   block_t: int):
-    # x_ref: (1, nchan, block_t + MAX_DELAY); delay_ref: (nchan, block_dm)
-    # out_ref: (block_dm, block_t)
-    acc = jnp.zeros((block_dm, block_t), jnp.float32)
+def _dedisp_kernel(delay_ref, x_ref, out_ref, acc_ref, *, nchan: int,
+                   block_dm: int, block_t: int):
+    # delay_ref: (nchan, ndm) in SMEM; x_ref: (1, nchan, block_t + MAX_DELAY)
+    # out_ref/acc_ref: (block_dm, block_t)
+    dm0 = pl.program_id(0) * block_dm
+    width = block_t + MAX_DELAY
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def chan_body(c, acc):
-        row = x_ref[0, c, :]
+    def chan_body(c, carry):
+        row = x_ref[0, pl.ds(c, 1), :]  # (1, width)
 
-        def dm_body(i, acc):
-            off = delay_ref[c, i]
-            seg = jax.lax.dynamic_slice(row, (off,), (block_t,))
-            return acc.at[i, :].add(seg.astype(jnp.float32))
+        def dm_body(i, carry):
+            off = delay_ref[c, dm0 + i]
+            seg = pltpu.roll(row, (width - off) % width, 1)[:, :block_t]
+            acc_ref[pl.ds(i, 1), :] += seg.astype(jnp.float32)
+            return carry
 
-        return jax.lax.fori_loop(0, block_dm, dm_body, acc)
+        return jax.lax.fori_loop(0, block_dm, dm_body, carry)
 
-    acc = jax.lax.fori_loop(0, nchan, chan_body, acc)
-    out_ref[...] = acc.astype(out_ref.dtype)
+    jax.lax.fori_loop(0, nchan, chan_body, 0)
+    out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_dm", "block_t", "interpret"))
@@ -97,15 +100,16 @@ def dedisperse(x: jax.Array, delays: jax.Array, *, block_dm: int = 32,
                                block_t=block_t)
     return pl.pallas_call(
         kernel,
-        grid=(ndm // block_dm, n_t),
-        in_specs=[
-            pl.BlockSpec((nchan, block_dm), lambda i, j: (0, i)),
-            pl.BlockSpec((1, nchan, block_t + MAX_DELAY),
-                         lambda i, j: (j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_dm, block_t), lambda i, j: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(ndm // block_dm, n_t),
+            in_specs=[pl.BlockSpec((1, nchan, block_t + MAX_DELAY),
+                                   lambda i, j, d: (j, 0, 0))],
+            out_specs=pl.BlockSpec((block_dm, block_t),
+                                   lambda i, j, d: (i, j)),
+            scratch_shapes=[pltpu.VMEM((block_dm, block_t), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((ndm, nt_out), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(delays, strips)[:ndm0, :nt_out0]
@@ -130,7 +134,7 @@ def dedisperse_ref(x: jax.Array, delays: jax.Array, **_unused) -> jax.Array:
 
 
 # ----------------------------------------------------------- live recording
-def make_live(problem: Mapping | None = None):
+def make_live(problem: Mapping | None, interpret: bool):
     """Recorder callable: fixed signal + delay table; chan_chunk/layout/
     unroll tunables are cost-model-only."""
     p = {**SMOKE_PROBLEM, **(problem or {})}
@@ -140,7 +144,7 @@ def make_live(problem: Mapping | None = None):
 
     def fn(conf: Mapping) -> None:
         out = dedisperse(x, delays, block_dm=conf["block_dm"],
-                         block_t=conf["block_t"], interpret=True)
+                         block_t=conf["block_t"], interpret=interpret)
         jax.block_until_ready(out)
 
     return fn

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 from ..core.costmodel import KernelWorkload, alignment_eff
 from ..core.devices import DeviceModel
 from ..core.searchspace import SearchSpace
@@ -116,7 +114,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -147,7 +145,7 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 # ------------------------------------------------------------ search space
-def make_live(problem: Mapping | None = None):
+def make_live(problem: Mapping | None, interpret: bool):
     """Recorder callable: causal GQA attention on fixed q/k/v; the
     accumulator-dtype tunable is cost-model-only."""
     p = {**SMOKE_PROBLEM, **(problem or {})}
@@ -159,7 +157,7 @@ def make_live(problem: Mapping | None = None):
     def fn(conf: Mapping) -> None:
         out = flash_attention(q, k, v, block_q=conf["block_q"],
                               block_kv=conf["block_kv"], causal=True,
-                              interpret=True)
+                              interpret=interpret)
         jax.block_until_ready(out)
 
     return fn

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 from ..core.costmodel import KernelWorkload, alignment_eff, dma_eff
 from ..core.devices import DeviceModel
 from ..core.searchspace import SearchSpace
@@ -85,7 +83,7 @@ def gemm(a: jax.Array, b: jax.Array, c0: jax.Array, *, block_m: int = 128,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, c0)[:m0, :n0]
@@ -100,11 +98,12 @@ def gemm_ref(a: jax.Array, b: jax.Array, c0: jax.Array, *, alpha: float = 1.0,
 
 
 # ----------------------------------------------------------- live recording
-def make_live(problem: Mapping | None = None):
-    """Interpret-mode evaluation callable for the recorder: fixed inputs,
-    ``fn(config_dict)`` runs the Pallas kernel with that tiling and blocks
-    until ready. Tunables the TPU wrapper does not consume (grid order,
-    accumulator dtype) are cost-model-only and ignored here."""
+def make_live(problem: Mapping | None, interpret: bool):
+    """Evaluation callable for the recorder: fixed inputs,
+    ``fn(config_dict)`` runs the Pallas kernel with that tiling (in
+    interpret mode when ``interpret``) and blocks until ready. Tunables
+    the TPU wrapper does not consume (grid order, accumulator dtype) are
+    cost-model-only and ignored here."""
     p = {**SMOKE_PROBLEM, **(problem or {})}
     ks = jax.random.split(jax.random.PRNGKey(p.get("seed", 0)), 3)
     a = jax.random.normal(ks[0], (p["m"], p["k"]), jnp.float32).astype(jnp.bfloat16)
@@ -113,7 +112,7 @@ def make_live(problem: Mapping | None = None):
 
     def fn(conf: Mapping) -> None:
         out = gemm(a, b, c0, block_m=conf["block_m"], block_n=conf["block_n"],
-                   block_k=conf["block_k"], interpret=True)
+                   block_k=conf["block_k"], interpret=interpret)
         jax.block_until_ready(out)
 
     return fn

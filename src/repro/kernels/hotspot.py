@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 from ..core.costmodel import KernelWorkload, alignment_eff, dma_eff
 from ..core.devices import DeviceModel
 from ..core.searchspace import SearchSpace
@@ -98,7 +96,7 @@ def hotspot(temp: jax.Array, power: jax.Array, *, strip_h: int = 64,
         ],
         out_specs=pl.BlockSpec((1, strip_h, block_w), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tiles, strip_h, block_w), temp.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(ts, ps)
@@ -121,7 +119,7 @@ def hotspot_ref(temp: jax.Array, power: jax.Array, *, t_block: int = 1,
 
 
 # ----------------------------------------------------------- live recording
-def make_live(problem: Mapping | None = None):
+def make_live(problem: Mapping | None, interpret: bool):
     """Recorder callable: ``t_block`` fused stencil steps on a fixed grid.
     Constraints bound to the problem size (divisibility, pyramid halo) are
     enforced by ``space(h, w)``; dtype/grid-order tunables are
@@ -134,7 +132,7 @@ def make_live(problem: Mapping | None = None):
 
     def fn(conf: Mapping) -> None:
         out = hotspot(t, pw, strip_h=conf["strip_h"], block_w=conf["block_w"],
-                      t_block=conf["t_block"], interpret=True)
+                      t_block=conf["t_block"], interpret=interpret)
         jax.block_until_ready(out)
 
     return fn

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 from ..core.costmodel import KernelWorkload, alignment_eff
 from ..core.devices import DeviceModel
 from ..core.searchspace import SearchSpace
@@ -36,47 +34,63 @@ from ..core.tunable import Constraint, tunables_from_dict
 SMOKE_PROBLEM = {"bh": 4, "seq": 256, "p": 32, "n": 32}
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
-                chunk: int):
+def _ssd_kernel(x_ref, dt_row_ref, dt_col_ref, a_ref, b_ref, c_ref, y_ref,
+                h_ref, *, chunk: int):
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0].astype(jnp.float32)     # (Q, P)
-    dt = dt_ref[0].astype(jnp.float32)   # (Q,)
-    a = a_ref[0]                          # scalar A (negative)
-    b = b_ref[0].astype(jnp.float32)     # (Q, N)
-    c = c_ref[0].astype(jnp.float32)     # (Q, N)
+    x = x_ref[0].astype(jnp.float32)            # (Q, P)
+    dt_row = dt_row_ref[0, pl.ds(ci, 1), :]     # (1, Q)
+    dt_col = dt_col_ref[0]                      # (Q, 1)
+    a = a_ref[0]                                # (1, 1) scalar A (negative)
+    b = b_ref[0].astype(jnp.float32)            # (Q, N)
+    c = c_ref[0].astype(jnp.float32)            # (Q, N)
 
-    log_decay = dt * a                    # (Q,) log per-step decay
-    cum = jnp.cumsum(log_decay)           # (Q,) cumulative within chunk
-    # intra-chunk: mask[i,j] = exp(cum_i - cum_j) for j <= i (strict decay
-    # between step j and i), scaled by dt_j
-    li = cum[:, None] - cum[None, :]
+    # within-chunk prefix sums of the log per-step decay dt·A, as matmuls
+    # with triangular masks: Mosaic lowers no cumsum, and broadcasts only
+    # along one of sublanes/lanes at a time, so dt arrives both as a row
+    # and as a column. cum_i[i, :] = cum_i and cum_j[:, j] = cum_j.
+    hi = jax.lax.Precision.HIGHEST
     iota_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     iota_j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    mask = iota_i >= iota_j
-    decay_ij = jnp.where(mask, jnp.exp(li), 0.0)
+    lower = iota_i >= iota_j
+    ld_col = dt_col * a
+    cum_i = jax.lax.dot(lower.astype(jnp.float32),
+                        jnp.broadcast_to(ld_col, (chunk, chunk)),
+                        precision=hi, preferred_element_type=jnp.float32)
+    cum_j = jax.lax.dot(jnp.broadcast_to(dt_row * a, (chunk, chunk)),
+                        (iota_i <= iota_j).astype(jnp.float32),
+                        precision=hi, preferred_element_type=jnp.float32)
+    cum = cum_i[:, :1]                          # (Q, 1)
+    total = cum_j[:1, chunk - 1:]               # (1, 1)
+    # intra-chunk: mask[i,j] = exp(cum_i - cum_j) for j <= i (strict decay
+    # between step j and i), scaled by dt_j
+    decay_ij = jnp.where(lower, jnp.exp(cum_i - cum_j), 0.0)
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Q, Q)
-    w = cb * decay_ij * dt[None, :]
+    w = cb * decay_ij * dt_row
     y_intra = jax.lax.dot(w, x, preferred_element_type=jnp.float32)
 
     # inter-chunk: y_inter_i = exp(cum_i) * C_i · h_in
-    h_in = h_ref[...]                     # (N, P)
-    y_inter = jnp.exp(cum)[:, None] * jax.lax.dot(
+    h_in = h_ref[...]                           # (N, P)
+    y_inter = jnp.exp(cum) * jax.lax.dot(
         c, h_in, preferred_element_type=jnp.float32)
 
     y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # state update: h_out = exp(total) * h_in + Σ_j exp(total - cum_j)·dt_j·B_j⊗X_j
-    total = cum[-1]
-    suffix = jnp.exp(total - cum) * dt    # (Q,)
-    bx = jax.lax.dot_general(b * suffix[:, None], x, (((0,), (0,)), ((), ())),
+    suffix = jnp.exp(total - cum) * dt_col      # (Q, 1)
+    bx = jax.lax.dot_general(b * suffix, x, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (N, P)
-    h_ref[...] = jnp.exp(total) * h_in + bx
+    # exp(total) as an (N, 1) column: a (1, 1) -> (N, P) broadcast would
+    # cross sublanes and lanes at once
+    decay_n = jnp.exp(jax.lax.dot(
+        jnp.ones((h_in.shape[0], chunk), jnp.float32), ld_col,
+        precision=hi, preferred_element_type=jnp.float32))
+    h_ref[...] = decay_n * h_in + bx
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -86,6 +100,8 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     """SSD scan for flattened (batch·heads) leading dim.
 
     x: (BH, L, P); dt: (BH, L); a: (BH,); b/c: (BH, L, N). Returns y like x.
+    ``dt`` and ``a`` are passed in blocks whose last two dimensions equal
+    the array's, which the TPU's (8, 128) tiling rule accepts at any chunk.
     """
     bh, l, p = x.shape
     n = b.shape[-1]
@@ -97,18 +113,20 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         grid=(bh, n_chunks),
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda h, i: (h, i, 0)),
-            pl.BlockSpec((1, chunk), lambda h, i: (h, i)),
-            pl.BlockSpec((1,), lambda h, i: (h,)),
+            pl.BlockSpec((1, n_chunks, chunk), lambda h, i: (h, 0, 0)),
+            pl.BlockSpec((1, chunk, 1), lambda h, i: (h, i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda h, i: (h, 0, 0)),
             pl.BlockSpec((1, chunk, n), lambda h, i: (h, i, 0)),
             pl.BlockSpec((1, chunk, n), lambda h, i: (h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, p), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, a, b, c)
+    )(x, dt.reshape(bh, n_chunks, chunk), dt.reshape(bh, l, 1),
+      a.reshape(bh, 1, 1), b, c)
 
 
 # -------------------------------------------------------------------- ref
@@ -136,7 +154,7 @@ def ssd_ref(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
 
 
 # ------------------------------------------------------------ search space
-def make_live(problem: Mapping | None = None):
+def make_live(problem: Mapping | None, interpret: bool):
     """Recorder callable: chunked SSD scan on fixed inputs; state_block and
     accumulator-dtype tunables are cost-model-only."""
     p = {**SMOKE_PROBLEM, **(problem or {})}
@@ -149,7 +167,8 @@ def make_live(problem: Mapping | None = None):
     c = jax.random.normal(ks[4], (bh, l, p["n"]), jnp.float32)
 
     def fn(conf: Mapping) -> None:
-        out = ssd_scan(x, dt, a, b, c, chunk=conf["chunk"], interpret=True)
+        out = ssd_scan(x, dt, a, b, c, chunk=conf["chunk"],
+                       interpret=interpret)
         jax.block_until_ready(out)
 
     return fn

@@ -8,7 +8,7 @@ cost_analysis() and the collective schedule for the roofline analysis.
 must happen before jax initializes a backend (jax locks the device count
 at first init), which holds for the CLI entry because importing jax does
 not initialize one. It must NOT happen at module import: this module is a
-library too (``cost_analysis_dict`` feeds the scenario surrogate), and an
+library too (``lower_cell`` is imported by the tests), and an
 importing process — smoke tests, benches, the service — must keep seeing
 1 device. ``parity-lint``'s ``ordering-import-env-mutation`` rule
 enforces the distinction repo-wide.
@@ -146,15 +146,6 @@ def _lower_cell_inner(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
     return lowered
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns one dict on current jax but a
-    per-device list of dicts on 0.4.x; normalize to the (replicated) dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def run_cell(arch_name: str, shape_name: str, mesh_kind: str, *,
              microbatches: int = 1, remat: str = "full", layout: str = "2d",
              collect_hlo: bool = True) -> dict:
@@ -178,7 +169,7 @@ def run_cell(arch_name: str, shape_name: str, mesh_kind: str, *,
         compiled = lowered.compile()
         t_compile = time.perf_counter() - t0
         mem = compiled.memory_analysis()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         flops = float(cost.get("flops", 0.0))
         bytes_acc = float(cost.get("bytes accessed", 0.0))
         coll = parse_collectives(compiled.as_text(), n_chips) if collect_hlo \

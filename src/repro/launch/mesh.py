@@ -8,16 +8,10 @@ the inter-pod (DCN/ICI) links.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types (Auto is the pre-AxisType behaviour)
-    from jax.sharding import AxisType
-except ImportError:  # older jax: no AxisType, make_mesh takes no axis_types
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -16,10 +16,9 @@ Pricing the same config twice returns a bit-identical ``CachedResult`` —
 the property the ``modeled`` lookup tier and the conformance tests pin.
 
 For workloads that were actually compiled, ``facts_from_compiled`` reads
-XLA's compile-only cost analysis (via ``launch.dryrun.cost_analysis_dict``,
-which normalizes the list-vs-dict jax API difference) and
-``price_from_facts`` turns those measured FLOP/byte counts into the same
-roofline bound — the calibration path for non-registry workloads.
+XLA's compile-only cost analysis and ``price_from_facts`` turns those
+measured FLOP/byte counts into the same roofline bound — the calibration
+path for non-registry workloads.
 """
 from __future__ import annotations
 
@@ -106,10 +105,8 @@ def price_from_facts(facts: Mapping, device: DeviceModel,
 
 def facts_from_compiled(compiled) -> dict:
     """Compile-only dry-run facts for a ``jax`` ``Compiled`` object —
-    delegates to ``launch.dryrun.cost_analysis_dict`` (which papers over
-    the 0.4.x list-of-dicts return shape)."""
-    from ..launch.dryrun import cost_analysis_dict
-    return dict(cost_analysis_dict(compiled))
+    XLA's ``cost_analysis()``."""
+    return dict(compiled.cost_analysis())
 
 
 class SurrogateRunner(Runner):

@@ -255,6 +255,17 @@ class TestDeviceSyncRule:
                     run.spent = float(spent[i])
         """, path=self.PATH) == []
 
+    def test_view_of_conversion_is_host(self):
+        # reinterpreting the converted bits (float64 columns travel as
+        # int64 bit patterns) is a method of a host array
+        assert lint("""
+            def commit(rows, runs):
+                out = _replay_vjit(rows)
+                spent = np.asarray(out[4]).view(np.float64)
+                for i, run in enumerate(runs):
+                    run.spent = float(spent[i])
+        """, path=self.PATH) == []
+
     def test_bulk_conversion_outside_loop_passes(self):
         assert lint("""
             def once(rows):

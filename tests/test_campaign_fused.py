@@ -41,12 +41,7 @@ from repro.core.methodology import evaluate_strategy, make_scorer
 from repro.core.runner import SimulationRunner
 from repro.core.strategies import get_strategy
 
-pytestmark = [
-    pytest.mark.jax_engine,
-    pytest.mark.skipif(
-        not engine_jax.engine_available(),
-        reason=f"jax engine unavailable ({engine_jax.unavailable_reason()})"),
-]
+pytestmark = pytest.mark.jax_engine
 
 CACHE = parity_cache()
 TOTAL = total_charge(CACHE)

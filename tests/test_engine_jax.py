@@ -14,13 +14,15 @@ Two contracts, tested separately (see ``core.engine_jax``):
     replay numpy streams, so pinned seeds reproduce against themselves
     and distributions (best value, spend) match the numpy strategies.
 
-Marked ``jax_engine``; skipped with a reason when no jax backend can
-dispatch (the engine itself then degrades to the numpy path, covered by
-test_protocol.py's cross-engine resume tests which run everywhere).
+Marked ``jax_engine``. The engine runs on whatever platform JAX
+initialized (the CPU under ``JAX_PLATFORMS=cpu``); it never degrades to
+numpy.
 """
 import math
 import random
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from _compat import given, settings, st
@@ -34,12 +36,7 @@ from repro.core.runner import SimulationRunner
 from repro.core.space import RowBatch
 from repro.core.strategies import get_strategy
 
-pytestmark = [
-    pytest.mark.jax_engine,
-    pytest.mark.skipif(
-        not engine_jax.engine_available(),
-        reason=f"jax engine unavailable ({engine_jax.unavailable_reason()})"),
-]
+pytestmark = pytest.mark.jax_engine
 
 CACHE = parity_cache()
 TOTAL = total_charge(CACHE)
@@ -280,6 +277,26 @@ def test_free_run_budget_and_shape_invariants(name):
     # best rows are valid whenever a finite best exists
     finite = np.isfinite(out["best_value"])
     assert (out["best_row"][finite] >= 0).all()
+    # bests are recorded values, bit for bit
+    recorded = {r.time_s for r in CACHE.results.values()} | {math.inf}
+    assert set(out["curve_best"].ravel().tolist()) <= recorded
+
+
+def test_order_key_orders_like_the_values():
+    """free_run tracks its best value as ``_order_key`` of its bit pattern:
+    integer order must be value order, signs and extremes included, and
+    the key must map back to the same bits."""
+    from repro.core.engine_jax.strategies import _order_key
+    from repro.core.engine_jax.tables import f64_bits
+    rng = np.random.default_rng(3)
+    x = np.concatenate([
+        rng.standard_normal(500) * 10.0 ** rng.integers(-300, 300, 500),
+        [0.0, 5e-324, -5e-324, 1.0, -1.0, math.inf, -math.inf]])
+    with jax.enable_x64():
+        key = np.asarray(jax.jit(_order_key)(jnp.asarray(f64_bits(x))))
+        back = np.asarray(jax.jit(_order_key)(jnp.asarray(key)))
+    assert np.array_equal(np.argsort(key), np.argsort(x))
+    assert np.array_equal(back, f64_bits(x))
 
 
 def test_free_run_random_search_exhausts_space_exactly():
@@ -326,6 +343,40 @@ def test_free_run_rejects_unknown_hyperparameters():
                             crossover="uniform")
 
 
+# ------------------------------------------------------ float64 on bits
+def _f64_cases():
+    rng = np.random.default_rng(11)
+    n = 4096
+    big = np.finfo(np.float64).max
+    frac = rng.random(n)
+    return {
+        "lognormal": (rng.lognormal(-3, 3, n), rng.lognormal(-3, 3, n)),
+        "all-exponents": (np.ldexp(rng.random(n), rng.integers(-1074, 1000, n)),
+                          np.ldexp(rng.random(n), rng.integers(-1074, 1000, n))),
+        "one-ulp": (frac, np.nextafter(frac, 2.0) - frac),
+        "subnormal": (rng.random(n) * 1e-310, rng.random(n) * 1e-310),
+        "overflow": (big * rng.random(n), big * rng.random(n)),
+        "ties": (np.ldexp(rng.integers(1, 2 ** 53, n).astype(float), -52),
+                 np.ldexp(rng.integers(1, 8, n).astype(float),
+                          rng.integers(-60, -50, n))),
+        "zero": (np.zeros(n), frac),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_f64_cases()))
+def test_f64_add_bits_is_ieee_addition(case):
+    """The device adds float64 in integer arithmetic on the bit patterns
+    (the TPU has no float64 unit): every sum must be numpy's, bit for bit,
+    round-half-even ties, subnormals and overflow to inf included."""
+    from repro.core.engine_jax.replay import as_f64, f64_add_bits, f64_bits
+    a, b = _f64_cases()[case]
+    with jax.enable_x64(), np.errstate(over="ignore"):
+        got = as_f64(jax.jit(f64_add_bits)(jnp.asarray(f64_bits(a)),
+                                           jnp.asarray(f64_bits(b))))
+        want = a + b
+    assert np.array_equal(f64_bits(got), f64_bits(want))
+
+
 # ------------------------------------------------------------------ tables
 def test_tables_are_memoized_and_x64():
     compiled = CACHE.space.compiled
@@ -334,8 +385,13 @@ def test_tables_are_memoized_and_x64():
     assert engine_jax.replay_tables(cols, compiled) is rt
     st_ = engine_jax.space_tables(compiled)
     assert engine_jax.space_tables(compiled) is st_
-    assert str(rt.time_s.dtype) == "float64"
-    assert str(rt.charge_s.dtype) == "float64"
+    # float64 columns travel as their exact bit patterns
+    assert str(rt.time_s.dtype) == "int64"
+    assert str(rt.charge_s.dtype) == "int64"
+    assert np.array_equal(np.asarray(rt.time_s).view(np.float64),
+                          cols.time_s, equal_nan=True)
+    assert np.array_equal(np.asarray(rt.charge_s).view(np.float64),
+                          cols.charge_s)
     assert str(rt.col_of_row.dtype) == "int32"
 
 

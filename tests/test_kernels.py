@@ -126,3 +126,43 @@ def test_hub_kernel_spaces_have_failures():
                for cfg in space.valid_configs):
             failing += 1
     assert failing >= 2
+
+
+# ------------------------------------------------------ live: interpret mode
+LIVE_WRAPPERS = {"gemm": "gemm", "convolution": "conv2d",
+                 "dedispersion": "dedisperse",
+                 "flash_attention": "flash_attention", "hotspot": "hotspot",
+                 "ssd": "ssd_scan"}
+
+
+def test_live_device_follows_the_platform(monkeypatch):
+    from types import SimpleNamespace
+
+    from repro.core.devices import live_device
+    assert live_device() == ("cpu_interpret", True)
+    monkeypatch.setattr(jax, "devices", lambda: [
+        SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")])
+    assert live_device() == ("tpu_v5_lite", False)
+
+
+@pytest.mark.parametrize("interpret", [True, False])
+@pytest.mark.parametrize("name", sorted(ALL_KERNELS))
+def test_make_live_takes_interpret_from_the_platform(monkeypatch, name,
+                                                     interpret):
+    """Every live objective compiles for the chip on a TPU and interprets
+    elsewhere — never a hard-coded mode."""
+    import repro.kernels
+    spec = repro.kernels.get_kernel(name)
+    module = ALL_KERNELS[name]
+    label = "cpu_interpret" if interpret else "tpu_v5_lite"
+    monkeypatch.setattr(repro.kernels, "live_device",
+                        lambda: (label, interpret))
+    seen = []
+
+    def wrapper(*_args, **kw):
+        seen.append(kw["interpret"])
+        return jnp.zeros(1)
+    monkeypatch.setattr(module, LIVE_WRAPPERS[name], wrapper)
+    space = spec.space()
+    spec.make_live()(space.as_dict(space.valid_configs[0]))
+    assert seen == [interpret]

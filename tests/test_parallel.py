@@ -66,8 +66,8 @@ def test_parallel_evaluate_strategy_bit_identical():
 
 def test_jax_device_arrays_never_pickle():
     """The jax engine memoizes device-array mirrors on ``CacheColumns`` and
-    ``CompiledSpace`` (``_jax``); a pool worker must re-materialize them
-    against its own backend (or fall back to numpy), never inherit device
+    ``CompiledSpace`` (``_jax``); a process that unpickles them must
+    re-materialize them against its own backend, never inherit device
     handles — so pickles drop them, even mid-campaign."""
     import pickle
 
@@ -77,8 +77,7 @@ def test_jax_device_arrays_never_pickle():
 
     cache = _cache(3)
     runner = SimulationRunner(cache, Budget(max_evals=30), engine="jax")
-    # populate the device-table memos (a no-op without a jax backend —
-    # the pickle contract must hold either way)
+    # populate the device-table memos
     runner.run_batch(RowBatch(cache.space.compiled,
                               np.arange(20, dtype=np.int64)))
     cols, cs = cache.columns, cache.space.compiled
@@ -94,9 +93,9 @@ def test_jax_device_arrays_never_pickle():
 
 
 def test_parallel_jax_scorers_bit_identical_to_serial():
-    """engine="jax" scorers fan out to process workers: each worker
-    re-probes its own backend (using it when present, numpy otherwise) and
-    the campaign is bit-identical to the serial run regardless."""
+    """engine="jax" scorers handed a process pool keep their device work
+    in this process (the pool stays idle), bit-identical to the serial
+    run."""
     scorers = [make_scorer(_cache(), engine="jax")]
     factory = StrategyFactory.create("genetic_algorithm", {})
     serial = evaluate_strategy(factory, scorers, repeats=2, seed=0)
@@ -267,3 +266,71 @@ def test_cli_meta(cache_path, tmp_path, capsys):
     assert "best hyperparameters" in out
     assert cli_main(["report", journal]) == 0
     assert "campaign: meta" in capsys.readouterr().out
+
+
+# ------------------------------------------------ one process for the chip
+@pytest.fixture
+def no_worker_processes(monkeypatch):
+    """Fail any attempt to start a worker process: a child of a process
+    that holds the chip cannot open it."""
+    from repro.core import parallel
+
+    def refuse(*_a, **_kw):
+        raise AssertionError("started a worker process")
+    monkeypatch.setattr(parallel, "ProcessPoolExecutor", refuse)
+
+
+@pytest.mark.parametrize("backend", ["auto", "process"])
+def test_jax_engine_workers_stay_in_process(cache_path, no_worker_processes,
+                                            capsys, backend):
+    """``--workers 2 --engine jax``: simulate and hypertune grids run as
+    fused device campaigns in this process, whatever the pool backend."""
+    args = ["--cache", cache_path, "--strategy", "pso", "--engine", "jax",
+            "--repeats", "2", "--workers", "2", "--backend", backend]
+    assert cli_main(["simulate", *args]) == 0
+    assert "drive: device" in capsys.readouterr().out
+    assert cli_main(["hypertune", *args, "--quiet"]) == 0
+    assert "drive: device" in capsys.readouterr().out
+
+
+def test_live_record_workers_stay_in_process(tmp_path, no_worker_processes):
+    out = str(tmp_path / "hs.json.gz")
+    assert cli_main(["record", "--kernel", "hotspot", "--workers", "2",
+                     "--max-evals", "2", "--repeats", "1",
+                     "--out", out]) == 0
+    assert CacheFile.load(out).device == "cpu_interpret"
+    with pytest.raises(SystemExit, match="--backend thread"):
+        cli_main(["record", "--kernel", "hotspot", "--workers", "2",
+                  "--backend", "process", "--max-evals", "2",
+                  "--out", str(tmp_path / "x.json.gz")])
+
+
+def test_compiled_live_record_runs_shards_one_after_another(
+        tmp_path, monkeypatch, no_worker_processes):
+    """On a TPU each live evaluation times its kernel by the host clock, so
+    ``--workers 3`` records its three shards one after another in this
+    thread: no kernel ever waits behind another worker's."""
+    import threading
+
+    from repro.core import record
+    from repro.kernels import gemm
+    monkeypatch.setattr(record, "live_device", lambda: ("tpu_v5_lite", False))
+    calls, active, peak = [], [0], [0]
+
+    def make_live(_problem, interpret):
+        def fn(_conf):
+            active[0] += 1
+            peak[0] = max(peak[0], active[0])
+            calls.append((threading.get_ident(), interpret))
+            active[0] -= 1
+        return fn
+    monkeypatch.setattr(gemm, "make_live", make_live)
+    out = str(tmp_path / "g.json.gz")
+    assert cli_main(["record", "--kernel", "gemm", "--workers", "3",
+                     "--backend", "thread", "--max-evals", "2",
+                     "--repeats", "1", "--out", out]) == 0
+    assert len(calls) >= 3 and peak[0] == 1
+    assert set(calls) == {(threading.get_ident(), False)}
+    assert CacheFile.load(out).device == "tpu_v5_lite"
+    assert all((tmp_path / f"g.shard-{w:02d}.jsonl").exists()
+               for w in range(3))

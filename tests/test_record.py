@@ -316,3 +316,35 @@ def test_cli_rejects_unknown_kernel(tmp_path):
     with pytest.raises(SystemExit, match="unknown kernel"):
         cli_main(["record", "--kernel", "nope",
                   "--out", str(tmp_path / "x.json")])
+
+
+# ------------------------------------------------------------ device label
+def test_live_spec_is_labelled_with_its_device():
+    """A live recording is labelled with the device it runs on (here the
+    CPU, in interpret mode); a ``--device`` naming another one is an
+    error, while the cost model keeps its device-model default."""
+    assert RecordSpec.create("gemm", runner="live").device == "cpu_interpret"
+    assert RecordSpec.create("gemm", runner="live",
+                             device="cpu_interpret").device == "cpu_interpret"
+    with pytest.raises(ValueError, match="runs on 'cpu_interpret'"):
+        RecordSpec.create("gemm", runner="live", device="tpu_v5e")
+    assert RecordSpec.create("gemm", runner="costmodel").device == "tpu_v5e"
+    assert RecordSpec.create("gemm", runner="costmodel",
+                             device="tpu_v4").device == "tpu_v4"
+
+
+def test_live_kernel_with_no_runnable_config_is_a_fault(tmp_path,
+                                                       monkeypatch):
+    """Compile refusals are ``error`` results, but when every config
+    fails the recording fails, naming the first refusal."""
+    from repro.kernels import gemm
+
+    def make_live(_problem, _interpret):
+        def fn(_conf):
+            raise RuntimeError("Mosaic failed to compile TPU kernel\nmore")
+        return fn
+    monkeypatch.setattr(gemm, "make_live", make_live)
+    with pytest.raises(SystemExit, match="no config of gemm ran on "
+                       "cpu_interpret .*RuntimeError: Mosaic failed"):
+        cli_main(["record", "--kernel", "gemm", "--max-evals", "3",
+                  "--repeats", "1", "--out", str(tmp_path / "g.json.gz")])

@@ -155,7 +155,7 @@ def test_analytic_cost_matches_xla_on_trip_count_one():
     """With L=1, one KV block and one microbatch every scan has trip count 1,
     so XLA's cost_analysis is exact — the analytic model must agree on FLOPs
     within 25 % (it approximates elementwise/softmax work)."""
-    from repro.launch.dryrun import cost_analysis_dict, lower_cell
+    from repro.launch.dryrun import lower_cell
     from repro.launch.mesh import make_host_mesh
 
     base = get_config("olmo-1b")
@@ -164,7 +164,7 @@ def test_analytic_cost_matches_xla_on_trip_count_one():
     shape = ShapeConfig("probe", seq_len=512, global_batch=4, kind="train")
     mesh = make_host_mesh()
     compiled = lower_cell(cfg, shape, mesh, remat="none").compile()
-    xla_flops = float(cost_analysis_dict(compiled)["flops"])
+    xla_flops = float(compiled.cost_analysis()["flops"])
     ours, _ = analytic_cost(cfg, shape, remat="none", n_chips=1)
     assert ours == pytest.approx(xla_flops, rel=0.25)
 

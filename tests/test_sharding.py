@@ -7,12 +7,7 @@ mesh — no 512-device init, which belongs to the dry-run only.
 import jax
 import numpy as np
 import pytest
-
-try:
-    from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
-except ImportError:  # jax < 0.5 has no AxisType / kwarg-style AbstractMesh
-    pytest.skip("jax.sharding.AxisType unavailable (jax too old)",
-                allow_module_level=True)
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
 from repro.configs import ARCHS, SHAPES, cell_supported
 from repro.distribution.sharding import (_spec_for_param, batch_shardings,
