@@ -1,0 +1,11 @@
+"""The window that no program span covers (the entry points and the
+harness), in milliseconds per configuration scored. With the four other
+``*_ms.hypertune`` metrics it adds up to the window per configuration
+(``program_spans.py``)."""
+import program_spans
+
+SPANS = (program_spans.UNATTRIBUTED,)
+
+
+def read(run):
+    return program_spans.ms_per_unit(run, SPANS)

@@ -40,6 +40,7 @@ from .core.methodology import (DEFAULT_CUTOFF, AggregateReport, SpaceScorer,
                                make_scorer)
 from .core.parallel import (CampaignExecutor, CampaignJournal,
                             in_process_backend)
+from .core.spans import RECORD_MERGE, span
 
 __all__ = ["Hub", "Tuner", "TuningRun", "describe_space",
            "hyperparam_space_stats", "lint"]
@@ -471,10 +472,11 @@ class Tuner:
             if runner == "live":
                 executor.shutdown()
         space = rec.registry_space(kernel, dict(problem or {}))
-        cache = rec.merge_shards(
-            [rec.shard_path(prefix, w) for w in range(n)], space=space,
-            meta={"mode": "bruteforce" if bruteforce else "record"})
-        cache.save(out)
+        with span(RECORD_MERGE):
+            cache = rec.merge_shards(
+                [rec.shard_path(prefix, w) for w in range(n)], space=space,
+                meta={"mode": "bruteforce" if bruteforce else "record"})
+            cache.save(out)
         best_cfg = best_val = None
         ok = [(r.time_s, k) for k, r in cache.results.items()
               if r.status == "ok"]

@@ -42,6 +42,7 @@ from jax import enable_x64
 from ..cache import CachedResult
 from ..runner import INVALID, Observation, SimulationRunner
 from ..space import RowBatch
+from ..spans import CAMPAIGN_BUILD, CAMPAIGN_STEP, REPLAY_DISPATCH, span
 from .replay import _budget_limits, _pad_len, _replay_vjit, first_occurrence
 from .tables import f64_bits, replay_tables
 
@@ -215,93 +216,95 @@ def _collect_segment(run: FusedRun, value_of_row: np.ndarray,
     return np.concatenate(parts_r), np.concatenate(parts_f)
 
 
-def _drive_group(runs: "list[FusedRun]", cols, compiled) -> int:
-    """Drive one cache group's runs to completion; returns the number of
-    device dispatches (the whole point: a handful, not ~10^4)."""
-    tables = replay_tables(cols, compiled)
-    col_map = cols.rows_for_space(compiled)
-    safe = np.clip(col_map, 0, None)
-    if tables.has_miss:
-        # non-empty cache (fuse_reason gates empty ones), so this is the
-        # same finite value every miss commit would compute lazily
-        mean_charge = runs[0].driver.runner.cache.mean_eval_charge()
-        value_of_row = np.where(col_map >= 0, cols.time_s[safe], np.inf)
-        charge_of_row = np.where(col_map >= 0, cols.charge_s[safe],
-                                 mean_charge)
-    else:
-        mean_charge = 0.0
-        value_of_row = cols.time_s[safe]
-        charge_of_row = cols.charge_s[safe]
-    dispatches = 0
+def _drive_group(runs: "list[FusedRun]", cols, compiled) -> None:
+    """Drive one cache group's runs to completion in a handful of device
+    dispatches (the whole point: not ~10^4), each inside one
+    ``repro.replay.dispatch`` span."""
+    with span(CAMPAIGN_BUILD):
+        tables = replay_tables(cols, compiled)
+        col_map = cols.rows_for_space(compiled)
+        safe = np.clip(col_map, 0, None)
+        if tables.has_miss:
+            # non-empty cache (fuse_reason gates empty ones), so this is
+            # the same finite value every miss commit would compute lazily
+            mean_charge = runs[0].driver.runner.cache.mean_eval_charge()
+            value_of_row = np.where(col_map >= 0, cols.time_s[safe], np.inf)
+            charge_of_row = np.where(col_map >= 0, cols.charge_s[safe],
+                                     mean_charge)
+        else:
+            mean_charge = 0.0
+            value_of_row = cols.time_s[safe]
+            charge_of_row = cols.charge_s[safe]
     active = [r for r in runs if not r.done]
     while active:
         todo: list = []
-        for run in active:
-            rows, fresh = _collect_segment(run, value_of_row, charge_of_row)
-            if len(rows) == 0:
-                run.done = True
-            else:
-                todo.append((run, rows, fresh))
+        with span(CAMPAIGN_STEP):
+            for run in active:
+                rows, fresh = _collect_segment(run, value_of_row,
+                                               charge_of_row)
+                if len(rows) == 0:
+                    run.done = True
+                else:
+                    todo.append((run, rows, fresh))
         if not todo:
             break
-        # pad both axes to powers of two so the jit cache holds a handful
-        # of (runs, length) shapes per space, not one per campaign round
-        length = _pad_len(max(len(rows) for _run, rows, _f in todo))
-        width = _pad_len(len(todo))
-        rows_m = np.zeros((width, length), dtype=np.int64)
-        fresh_m = np.zeros((width, length), dtype=bool)
-        spent0 = np.zeros(width, dtype=np.float64)
-        evals0 = np.zeros(width, dtype=np.int64)
-        max_s = np.full(width, np.inf, dtype=np.float64)
-        max_e = np.full(width, 2 ** 62, dtype=np.int64)
-        for i, (run, rows, fresh) in enumerate(todo):
-            rows_m[i, :len(rows)] = rows
-            fresh_m[i, :len(fresh)] = fresh
-            spent0[i] = run.spent
-            evals0[i] = run.evals
-            max_s[i] = run.max_s
-            max_e[i] = run.max_e
-        dispatches += 1
-        with enable_x64():
-            out = _replay_vjit(
-                jnp.asarray(rows_m), jnp.asarray(fresh_m),
-                tables.col_of_row, tables.time_s, tables.charge_s,
-                jnp.asarray(f64_bits(mean_charge)),
-                jnp.asarray(f64_bits(spent0)), jnp.asarray(evals0),
-                jnp.asarray(f64_bits(max_s)), jnp.asarray(max_e))
-        # float64 columns come back as bit patterns (see replay.py)
-        accept = np.asarray(out[0])
-        t_after = np.asarray(out[1]).view(np.float64)
-        value = np.asarray(out[2]).view(np.float64)
-        charge = np.asarray(out[3]).view(np.float64)
-        spent = np.asarray(out[4]).view(np.float64)
-        evals = np.asarray(out[5])
-        exhausted = np.asarray(out[6])
-        survivors: list = []
-        for i, (run, rows, _fresh) in enumerate(todo):
-            n = len(rows)
-            acc = np.nonzero(accept[i, :n])[0]
-            if len(acc):
-                run.acc_rows.append(rows[acc])
-                run.acc_t.append(t_after[i, acc])
-                run.acc_v.append(value[i, acc])
-                run.acc_c.append(charge[i, acc])
-            # chained-scan seed: the device's final (spent, evals) feeds
-            # the next segment, so the left-to-right addition sequence is
-            # one unbroken chain — bit-identical to a single long scan
-            run.spent = float(spent[i])
-            run.evals = int(evals[i])
-            run.approx_s = run.spent
-            run.approx_e = run.evals
-            if exhausted[i]:
-                run.exhausted = True
-                run.done = True
-            elif run.no_more_asks:
-                run.done = True
-            else:
-                survivors.append(run)
-        active = survivors
-    return dispatches
+        with span(REPLAY_DISPATCH):
+            # pad both axes to powers of two so the jit cache holds a handful
+            # of (runs, length) shapes per space, not one per campaign round
+            length = _pad_len(max(len(rows) for _run, rows, _f in todo))
+            width = _pad_len(len(todo))
+            rows_m = np.zeros((width, length), dtype=np.int64)
+            fresh_m = np.zeros((width, length), dtype=bool)
+            spent0 = np.zeros(width, dtype=np.float64)
+            evals0 = np.zeros(width, dtype=np.int64)
+            max_s = np.full(width, np.inf, dtype=np.float64)
+            max_e = np.full(width, 2 ** 62, dtype=np.int64)
+            for i, (run, rows, fresh) in enumerate(todo):
+                rows_m[i, :len(rows)] = rows
+                fresh_m[i, :len(fresh)] = fresh
+                spent0[i] = run.spent
+                evals0[i] = run.evals
+                max_s[i] = run.max_s
+                max_e[i] = run.max_e
+            with enable_x64():
+                out = _replay_vjit(
+                    jnp.asarray(rows_m), jnp.asarray(fresh_m),
+                    tables.col_of_row, tables.time_s, tables.charge_s,
+                    jnp.asarray(f64_bits(mean_charge)),
+                    jnp.asarray(f64_bits(spent0)), jnp.asarray(evals0),
+                    jnp.asarray(f64_bits(max_s)), jnp.asarray(max_e))
+            # float64 columns come back as bit patterns (see replay.py)
+            accept = np.asarray(out[0])
+            t_after = np.asarray(out[1]).view(np.float64)
+            value = np.asarray(out[2]).view(np.float64)
+            charge = np.asarray(out[3]).view(np.float64)
+            spent = np.asarray(out[4]).view(np.float64)
+            evals = np.asarray(out[5])
+            exhausted = np.asarray(out[6])
+            survivors: list = []
+            for i, (run, rows, _fresh) in enumerate(todo):
+                n = len(rows)
+                acc = np.nonzero(accept[i, :n])[0]
+                if len(acc):
+                    run.acc_rows.append(rows[acc])
+                    run.acc_t.append(t_after[i, acc])
+                    run.acc_v.append(value[i, acc])
+                    run.acc_c.append(charge[i, acc])
+                # chained-scan seed: the device's final (spent, evals) feeds
+                # the next segment, so the left-to-right addition sequence is
+                # one unbroken chain — bit-identical to a single long scan
+                run.spent = float(spent[i])
+                run.evals = int(evals[i])
+                run.approx_s = run.spent
+                run.approx_e = run.evals
+                if exhausted[i]:
+                    run.exhausted = True
+                    run.done = True
+                elif run.no_more_asks:
+                    run.done = True
+                else:
+                    survivors.append(run)
+            active = survivors
 
 
 def _commit_run(run: FusedRun) -> None:
@@ -367,18 +370,19 @@ def drive_fused(drivers, materialize: bool = True) -> "list[FusedRun]":
     """
     runs: list[FusedRun] = []
     groups: dict = {}
-    for d in drivers:
-        reason = fuse_reason(d)
-        if reason is not None:
-            raise ValueError(
-                f"driver is not device-fusable: {reason} "
-                f"(partition with fuse_reason first)")
-        run = FusedRun(d)
-        runs.append(run)
-        runner = d.runner
-        key = (id(runner.cache.columns), id(runner.space.compiled))
-        groups.setdefault(
-            key, (runner.cache.columns, runner.space.compiled, []))[2].append(run)
+    with span(CAMPAIGN_BUILD):
+        for d in drivers:
+            reason = fuse_reason(d)
+            if reason is not None:
+                raise ValueError(
+                    f"driver is not device-fusable: {reason} "
+                    f"(partition with fuse_reason first)")
+            run = FusedRun(d)
+            runs.append(run)
+            runner = d.runner
+            key = (id(runner.cache.columns), id(runner.space.compiled))
+            groups.setdefault(key, (runner.cache.columns,
+                                    runner.space.compiled, []))[2].append(run)
     for cols, compiled, group in groups.values():
         _drive_group(group, cols, compiled)
     if materialize:

@@ -40,6 +40,7 @@ from jax import enable_x64
 from ..budget import BudgetExhausted
 from ..cache import CachedResult
 from ..runner import Observation
+from ..spans import REPLAY_DISPATCH, span
 from .tables import ReplayTables, as_f64, f64_bits, replay_tables
 
 INVALID = float("inf")
@@ -202,23 +203,24 @@ class ReplayEngine:
                        if (col_rows[fresh] < 0).any() else 0.0)
         budget = runner.budget
         max_s, max_e = _budget_limits(budget)
-        npad = _pad_len(n)
-        rows_p = np.zeros(npad, dtype=np.int64)
-        rows_p[:n] = rows
-        fresh_p = np.zeros(npad, dtype=bool)
-        fresh_p[:n] = fresh
-        tables = replay_tables(cols, runner.space.compiled)
         self.dispatches += 1
-        with enable_x64():
-            out = _replay_jit(
-                jnp.asarray(rows_p), jnp.asarray(fresh_p),
-                tables.col_of_row, tables.time_s, tables.charge_s,
-                jnp.asarray(f64_bits(mean_charge)),
-                jnp.asarray(f64_bits(budget.spent_seconds)),
-                jnp.int64(budget.spent_evals),
-                jnp.asarray(f64_bits(max_s)), jnp.int64(max_e))
-            accept, t_after, value, charge, spent, evals, exhausted = (
-                _to_host(out))
+        with span(REPLAY_DISPATCH):
+            npad = _pad_len(n)
+            rows_p = np.zeros(npad, dtype=np.int64)
+            rows_p[:n] = rows
+            fresh_p = np.zeros(npad, dtype=bool)
+            fresh_p[:n] = fresh
+            tables = replay_tables(cols, runner.space.compiled)
+            with enable_x64():
+                out = _replay_jit(
+                    jnp.asarray(rows_p), jnp.asarray(fresh_p),
+                    tables.col_of_row, tables.time_s, tables.charge_s,
+                    jnp.asarray(f64_bits(mean_charge)),
+                    jnp.asarray(f64_bits(budget.spent_seconds)),
+                    jnp.int64(budget.spent_evals),
+                    jnp.asarray(f64_bits(max_s)), jnp.int64(max_e))
+                accept, t_after, value, charge, spent, evals, exhausted = (
+                    _to_host(out))
         # ------------------------------------------------- host-side commit
         # (mirrors _commit_rows_vectorized: fresh commits build
         # Observations, revisits gather from the row-indexed object array)

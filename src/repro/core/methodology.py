@@ -31,6 +31,7 @@ import numpy as np
 from .budget import Budget
 from .cache import CacheFile
 from .runner import SimulationRunner
+from .spans import CAMPAIGN_BUILD, SCORE, span
 from .strategies.base import Strategy
 
 DEFAULT_CUTOFF = 0.95
@@ -459,13 +460,14 @@ def run_repeats_device(scorer: SpaceScorer,
         return None
     t0 = time.perf_counter()
     drivers = []
-    for r in range(repeats):
-        runner = SimulationRunner(scorer.cache,
-                                  Budget(max_seconds=scorer.budget_s),
-                                  engine="jax")
-        drivers.append(SearchDriver(make_strategy(), scorer.cache.space,
-                                    runner, _repeat_rng(scorer, r, seed)))
-    reason = engine_jax.fuse_reason(drivers[0])
+    with span(CAMPAIGN_BUILD):
+        for r in range(repeats):
+            runner = SimulationRunner(scorer.cache,
+                                      Budget(max_seconds=scorer.budget_s),
+                                      engine="jax")
+            drivers.append(SearchDriver(make_strategy(), scorer.cache.space,
+                                        runner, _repeat_rng(scorer, r, seed)))
+        reason = engine_jax.fuse_reason(drivers[0])
     if reason is not None:
         for d in drivers:
             d.state.close()
@@ -476,10 +478,11 @@ def run_repeats_device(scorer: SpaceScorer,
     # scores straight from the committed improvement arrays: no Python
     # trace materializes on the scores-only path (score_improvements is
     # bit-identical to score_trace on the equivalent trace)
-    return [RepeatResult(scorer.score_improvements(*run.improvements(),
-                                                   times, baseline),
-                         run.fresh_evals, wall_share, run.spent)
-            for run in runs]
+    with span(SCORE):
+        return [RepeatResult(scorer.score_improvements(*run.improvements(),
+                                                       times, baseline),
+                             run.fresh_evals, wall_share, run.spent)
+                for run in runs]
 
 
 def _repeat_cell(ctx: tuple, si: int, r: int) -> RepeatResult:
@@ -530,8 +533,9 @@ def evaluate_strategy(make_strategy: Callable[[], Strategy],
     names = [s.name for s in scorers]
     if len(set(names)) != len(names):
         raise ValueError(f"duplicate space names in scorers: {names}")
-    times = [s.sample_times(n_samples) for s in scorers]
-    baselines = [s.baseline_at_time(t) for s, t in zip(scorers, times)]
+    with span(SCORE):
+        times = [s.sample_times(n_samples) for s in scorers]
+        baselines = [s.baseline_at_time(t) for s, t in zip(scorers, times)]
     cells_idx = [(si, r) for si in range(len(scorers)) for r in range(repeats)]
     cells: list[RepeatResult | None] = [None] * len(cells_idx)
     modes: list[str] = []
@@ -574,18 +578,19 @@ def evaluate_strategy(make_strategy: Callable[[], Strategy],
     fresh = 0
     wall = 0.0
     simulated = 0.0
-    for si, scorer in enumerate(scorers):
-        acc = np.zeros(n_samples)
-        for r in range(repeats):
-            cell = cells[si * repeats + r]
-            acc += cell.curve
-            fresh += cell.fresh_evals
-            wall += cell.wall_seconds
-            simulated += cell.simulated_seconds
-        curve = acc / repeats
-        per_space[scorer.name] = curve
-        per_space_score[scorer.name] = float(curve.mean())
-    mean_curve = np.mean(np.stack(list(per_space.values())), axis=0)
+    with span(SCORE):
+        for si, scorer in enumerate(scorers):
+            acc = np.zeros(n_samples)
+            for r in range(repeats):
+                cell = cells[si * repeats + r]
+                acc += cell.curve
+                fresh += cell.fresh_evals
+                wall += cell.wall_seconds
+                simulated += cell.simulated_seconds
+            curve = acc / repeats
+            per_space[scorer.name] = curve
+            per_space_score[scorer.name] = float(curve.mean())
+        mean_curve = np.mean(np.stack(list(per_space.values())), axis=0)
     fuse = modes[0] if len(set(modes)) == 1 else "mixed"
     return AggregateReport(float(mean_curve.mean()), mean_curve, per_space,
                            per_space_score, fresh, wall, simulated, fuse)

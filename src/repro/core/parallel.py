@@ -36,6 +36,7 @@ from typing import Any, Callable, Iterator, Mapping, Sequence
 import numpy as np
 
 from .methodology import AggregateReport, SpaceScorer, evaluate_strategy
+from .spans import JOURNAL_APPEND, span
 from .strategies import get_strategy
 
 JOURNAL_FORMAT = "repro-campaign"
@@ -345,13 +346,14 @@ class CampaignJournal:
         is inserted first so the new record starts on a fresh line — the
         torn fragment stays behind as one unparseable line that ``read``
         skips, and no later record is ever merged into it."""
-        payload = json.dumps(record) + "\n"
-        with open(self.path, "ab") as f:
-            if f.tell() > 0 and not self._ends_with_newline():
-                payload = "\n" + payload
-            f.write(payload.encode("utf-8"))
-            f.flush()
-            os.fsync(f.fileno())
+        with span(JOURNAL_APPEND):
+            payload = json.dumps(record) + "\n"
+            with open(self.path, "ab") as f:
+                if f.tell() > 0 and not self._ends_with_newline():
+                    payload = "\n" + payload
+                f.write(payload.encode("utf-8"))
+                f.flush()
+                os.fsync(f.fileno())
 
     def _ends_with_newline(self) -> bool:
         with open(self.path, "rb") as f:

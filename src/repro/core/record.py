@@ -38,6 +38,7 @@ from .devices import DEVICES_BY_NAME, live_device
 from .parallel import CampaignJournal
 from .runner import CostModelRunner, LiveRunner, Observation, Runner
 from .searchspace import SearchSpace
+from .spans import LIVE_INPUTS, span
 from .strategies import get_strategy
 
 SHARD_FORMAT = "repro-shard"
@@ -288,7 +289,8 @@ class RecordSpec:
     def make_runner(self, space: SearchSpace, budget: Budget) -> Runner:
         if self.runner == "live":
             spec = self.kernel_spec()
-            fn = spec.make_live(self.problem_dict, self.interpret)
+            with span(LIVE_INPUTS):
+                fn = spec.make_live(self.problem_dict, self.interpret)
             return LiveRunner(space, fn, budget, repeats=self.repeats)
         if self.runner == "costmodel":
             try:

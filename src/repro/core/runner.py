@@ -61,6 +61,7 @@ from .costmodel import KernelWorkload, estimate
 from .devices import DeviceModel
 from .searchspace import SearchSpace
 from .space import RowBatch
+from .spans import LIVE_FIRST_CALL, LIVE_TIMED, span
 from .tunable import Config
 
 INVALID = float("inf")
@@ -719,13 +720,15 @@ class LiveRunner(Runner):
         d = self.space.as_dict(config)
         t0 = time.perf_counter()
         try:
-            self.fn(d)  # warmup/compile
+            with span(LIVE_FIRST_CALL):
+                self.fn(d)  # warmup/compile
             compile_s = time.perf_counter() - t0
             times = []
-            for _ in range(self.repeats):
-                t1 = time.perf_counter()
-                self.fn(d)
-                times.append(time.perf_counter() - t1)
+            with span(LIVE_TIMED):
+                for _ in range(self.repeats):
+                    t1 = time.perf_counter()
+                    self.fn(d)
+                    times.append(time.perf_counter() - t1)
             return CachedResult("ok", sum(times) / len(times), tuple(times),
                                 compile_s)
         except Exception as e:
