@@ -2,7 +2,8 @@
 
 The fused-campaign throughput budget (docs/performance.md, "host↔device
 round-trip budget") hinges on one shape: a handful of vmapped dispatches,
-then *one* bulk ``np.asarray`` per output. An implicit element-wise sync —
+then *one* batched fetch of the outputs (``device_get``). An implicit
+element-wise sync —
 ``np.asarray``/``float()``/``.item()``/``.tolist()`` applied to a jax
 array inside a loop body — blocks on the device once per iteration and
 silently turns an O(dispatches) campaign back into the O(evaluations)
@@ -14,10 +15,13 @@ calls or jitted callables (any callable whose name contains ``jit``) are
 device values, and converting one inside a loop is an error **unless** the
 value was produced inside the same innermost loop's per-iteration region —
 the batched-output idiom of ``campaign._drive_group`` (dispatch in the
-loop, one bulk ``np.asarray`` per output right after it) stays clean,
+loop, one batched fetch right after it) stays clean,
 while per-element syncs of device values produced outside the loop (the
 ``(np.asarray(o) for o in out)`` shape) are flagged. A conversion's *result* is a host value: ``spent =
 np.asarray(out[4])`` then ``float(spent[i])`` in a loop syncs nothing.
+``jax.device_get`` and any method named ``device_get`` (the replay
+tables' batched fetch) convert like ``np.asarray``: their results are
+host arrays.
 """
 from __future__ import annotations
 
@@ -31,11 +35,19 @@ _CONVERT_CALLS = frozenset({
 })
 # conversion methods on array receivers
 _CONVERT_METHODS = frozenset({"item", "tolist"})
+# batched device->host fetches: ``jax.device_get`` or a method of that name
+_FETCH = "device_get"
 
 _DEVICE_ROOTS = ("jnp", "jax")
 
 _LOOPS = (ast.For, ast.While, ast.GeneratorExp, ast.ListComp,
           ast.SetComp, ast.DictComp)
+
+
+def _is_convert_call(name: "str | None") -> bool:
+    """A call that converts its first argument to host arrays."""
+    return name is not None and (name in _CONVERT_CALLS
+                                 or name.rsplit(".", 1)[-1] == _FETCH)
 
 
 def _is_device_call(node: ast.Call) -> bool:
@@ -52,7 +64,7 @@ def _is_conversion(node: ast.AST) -> bool:
     """Top-level host conversion: its result lives on the host."""
     if not isinstance(node, ast.Call):
         return False
-    if call_name(node) in _CONVERT_CALLS:
+    if _is_convert_call(call_name(node)):
         return True
     if not isinstance(node.func, ast.Attribute):
         return False
@@ -160,8 +172,7 @@ class DeviceSyncInLoop(Rule):
               "campaign path (benchmarks/check_regression.py)")
 
     def _conversion_arg(self, node: ast.Call) -> "ast.AST | None":
-        name = call_name(node)
-        if name in _CONVERT_CALLS and node.args:
+        if _is_convert_call(call_name(node)) and node.args:
             return node.args[0]
         if isinstance(node.func, ast.Attribute) \
                 and node.func.attr in _CONVERT_METHODS and not node.args:

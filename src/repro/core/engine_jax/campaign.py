@@ -25,6 +25,19 @@ the exhaustion point is discarded, which is exactly what ``BudgetExhausted``
 discards in the sequential loop: exhaustion is monotone (charges are
 non-negative), so the committed prefix is identical.
 
+Each dispatch is one host-device round trip. One ``device_put`` stages
+the segment's inputs (rows as int32, since every space has far fewer
+than 2**31 rows and ``col_of_row`` is int32 already; float64s as their
+int64 bit patterns), and one ``device_get`` fetches the five outputs
+only the device computes: the accept mask, the left-to-right spend
+``t_after`` and each run's ``(spent, evals, exhausted)``. The program
+still returns the 7-tuple of ``replay.py``; its ``value`` and ``charge``
+stay on the device, because the host's ``value_of_row`` /
+``charge_of_row`` are the same gathers of the same float64s
+(``time_s`` / ``charge_s`` through ``col_of_row``; inf and the cache's
+mean charge for a row outside the recorded set), so an accepted row's
+value and charge taken from them are bit-identical.
+
 Where draw counts are data-dependent (every strategy outside the allowlist,
 bridge-adapted loops, empty caches whose imputed-miss error must surface on
 the host), ``fuse_reason`` names the reason and the caller falls back to
@@ -34,9 +47,8 @@ state (tests/test_campaign_fused.py pins this against the numpy engine).
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
-
-import jax.numpy as jnp
 from jax import enable_x64
 
 from ..cache import CachedResult
@@ -44,7 +56,7 @@ from ..runner import INVALID, Observation, SimulationRunner
 from ..space import RowBatch
 from ..spans import CAMPAIGN_BUILD, CAMPAIGN_STEP, REPLAY_DISPATCH, span
 from .replay import _budget_limits, _pad_len, _replay_vjit, first_occurrence
-from .tables import f64_bits, replay_tables
+from .tables import as_f64, f64_bits, replay_tables
 
 # strategies whose ask/tell trajectory is host-replayable from values alone:
 # tell reads only ``observation.value`` (never status/config/result), and
@@ -219,7 +231,8 @@ def _collect_segment(run: FusedRun, value_of_row: np.ndarray,
 def _drive_group(runs: "list[FusedRun]", cols, compiled) -> None:
     """Drive one cache group's runs to completion in a handful of device
     dispatches (the whole point: not ~10^4), each inside one
-    ``repro.replay.dispatch`` span."""
+    ``repro.replay.dispatch`` span and each one put and one fetch (see
+    the module docstring)."""
     with span(CAMPAIGN_BUILD):
         tables = replay_tables(cols, compiled)
         col_map = cols.rows_for_space(compiled)
@@ -235,6 +248,8 @@ def _drive_group(runs: "list[FusedRun]", cols, compiled) -> None:
             mean_charge = 0.0
             value_of_row = cols.time_s[safe]
             charge_of_row = cols.charge_s[safe]
+        with enable_x64():
+            mean_charge_d = jax.device_put(f64_bits(mean_charge))
     active = [r for r in runs if not r.done]
     while active:
         todo: list = []
@@ -253,7 +268,7 @@ def _drive_group(runs: "list[FusedRun]", cols, compiled) -> None:
             # of (runs, length) shapes per space, not one per campaign round
             length = _pad_len(max(len(rows) for _run, rows, _f in todo))
             width = _pad_len(len(todo))
-            rows_m = np.zeros((width, length), dtype=np.int64)
+            rows_m = np.zeros((width, length), dtype=np.int32)
             fresh_m = np.zeros((width, length), dtype=bool)
             spent0 = np.zeros(width, dtype=np.float64)
             evals0 = np.zeros(width, dtype=np.int64)
@@ -266,30 +281,31 @@ def _drive_group(runs: "list[FusedRun]", cols, compiled) -> None:
                 evals0[i] = run.evals
                 max_s[i] = run.max_s
                 max_e[i] = run.max_e
+            rows_d, fresh_d, spent0_d, evals0_d, max_s_d, max_e_d = (
+                tables.device_put((rows_m, fresh_m, f64_bits(spent0), evals0,
+                                   f64_bits(max_s), max_e)))
             with enable_x64():
-                out = _replay_vjit(
-                    jnp.asarray(rows_m), jnp.asarray(fresh_m),
-                    tables.col_of_row, tables.time_s, tables.charge_s,
-                    jnp.asarray(f64_bits(mean_charge)),
-                    jnp.asarray(f64_bits(spent0)), jnp.asarray(evals0),
-                    jnp.asarray(f64_bits(max_s)), jnp.asarray(max_e))
+                out = _replay_vjit(rows_d, fresh_d, tables.col_of_row,
+                                   tables.time_s, tables.charge_s,
+                                   mean_charge_d, spent0_d, evals0_d,
+                                   max_s_d, max_e_d)
+            # only what the device alone computes; value and charge are
+            # the host's own gathers (value_of_row, charge_of_row)
+            accept, t_after, spent, evals, exhausted = tables.device_get(
+                (out[0], out[1], out[4], out[5], out[6]))
             # float64 columns come back as bit patterns (see replay.py)
-            accept = np.asarray(out[0])
-            t_after = np.asarray(out[1]).view(np.float64)
-            value = np.asarray(out[2]).view(np.float64)
-            charge = np.asarray(out[3]).view(np.float64)
-            spent = np.asarray(out[4]).view(np.float64)
-            evals = np.asarray(out[5])
-            exhausted = np.asarray(out[6])
+            t_after = as_f64(t_after)
+            spent = as_f64(spent)
             survivors: list = []
             for i, (run, rows, _fresh) in enumerate(todo):
                 n = len(rows)
                 acc = np.nonzero(accept[i, :n])[0]
                 if len(acc):
-                    run.acc_rows.append(rows[acc])
+                    acc_rows = rows[acc]
+                    run.acc_rows.append(acc_rows)
                     run.acc_t.append(t_after[i, acc])
-                    run.acc_v.append(value[i, acc])
-                    run.acc_c.append(charge[i, acc])
+                    run.acc_v.append(value_of_row[acc_rows])
+                    run.acc_c.append(charge_of_row[acc_rows])
                 # chained-scan seed: the device's final (spent, evals) feeds
                 # the next segment, so the left-to-right addition sequence is
                 # one unbroken chain — bit-identical to a single long scan

@@ -27,6 +27,13 @@ budget to it.
 
 Batches are padded to power-of-two lengths so the jit cache holds a handful
 of shapes per space instead of one per ask size.
+
+A dispatch moves its data in two transfer calls of its ``ReplayTables``:
+one ``device_put`` of every per-call input and one ``device_get`` of the
+outputs the host reads, whose copies all start before any is waited on.
+The programs return the 7-tuple ``(accept, t_after, value, charge, spent,
+evals, exhausted)``; ``ReplayEngine`` and ``replay_many`` fetch all seven,
+the fused campaign only the five the device alone computes (campaign.py).
 """
 from __future__ import annotations
 
@@ -55,12 +62,12 @@ _F64_ONE = 1 << 52          # the implicit leading bit of a binary64 significand
 _F64_INF = 0x7FF0 << 48     # bit pattern of +inf
 
 
-def _to_host(out) -> tuple:
+def _to_host(tables: ReplayTables, out) -> tuple:
     """A replay dispatch's outputs ``(accept, t_after, value, charge,
-    spent, evals, exhausted)`` as host arrays, the float64 columns
-    reinterpreted from their bit patterns."""
+    spent, evals, exhausted)`` as host arrays, fetched in one transfer
+    call, the float64 columns reinterpreted from their bit patterns."""
     accept, t_after, value, charge, spent, evals, exhausted = (
-        np.asarray(o) for o in out)
+        tables.device_get(tuple(out)))
     return (accept, as_f64(t_after), as_f64(value), as_f64(charge),
             as_f64(spent), evals, exhausted)
 
@@ -211,16 +218,19 @@ class ReplayEngine:
             fresh_p = np.zeros(npad, dtype=bool)
             fresh_p[:n] = fresh
             tables = replay_tables(cols, runner.space.compiled)
+            (rows_d, fresh_d, mean_charge_d, spent0_d, evals0_d, max_s_d,
+             max_e_d) = tables.device_put(
+                (rows_p, fresh_p, f64_bits(mean_charge),
+                 f64_bits(budget.spent_seconds),
+                 np.int64(budget.spent_evals), f64_bits(max_s),
+                 np.int64(max_e)))
             with enable_x64():
-                out = _replay_jit(
-                    jnp.asarray(rows_p), jnp.asarray(fresh_p),
-                    tables.col_of_row, tables.time_s, tables.charge_s,
-                    jnp.asarray(f64_bits(mean_charge)),
-                    jnp.asarray(f64_bits(budget.spent_seconds)),
-                    jnp.int64(budget.spent_evals),
-                    jnp.asarray(f64_bits(max_s)), jnp.int64(max_e))
-                accept, t_after, value, charge, spent, evals, exhausted = (
-                    _to_host(out))
+                out = _replay_jit(rows_d, fresh_d, tables.col_of_row,
+                                  tables.time_s, tables.charge_s,
+                                  mean_charge_d, spent0_d, evals0_d,
+                                  max_s_d, max_e_d)
+            accept, t_after, value, charge, spent, evals, exhausted = (
+                _to_host(tables, out))
         # ------------------------------------------------- host-side commit
         # (mirrors _commit_rows_vectorized: fresh commits build
         # Observations, revisits gather from the row-indexed object array)
@@ -287,26 +297,26 @@ def replay_many(cols, compiled, rows_matrix, *, seen=None,
         tables = replay_tables(cols, compiled)
     rows_matrix = np.asarray(rows_matrix, dtype=np.int64)
     runs, _n = rows_matrix.shape
+    if seen is None:
+        fresh = np.ones(rows_matrix.shape, dtype=bool)
+    else:
+        seen = np.asarray(seen)
+        fresh = ~seen[rows_matrix] if seen.ndim == 1 \
+            else ~np.take_along_axis(seen, rows_matrix, axis=1)
+
+    def per_run(x, default, dtype):
+        if x is None:
+            x = default
+        arr = (f64_bits(x) if dtype == np.float64
+               else np.asarray(x, dtype=dtype))
+        return np.broadcast_to(arr, (runs,))
+
+    rows_d, fresh_d, mean_charge_d, *budgets = tables.device_put(
+        (rows_matrix, fresh, f64_bits(mean_charge),
+         per_run(spent0, 0.0, np.float64), per_run(evals0, 0, np.int64),
+         per_run(max_seconds, _NO_MAX_S, np.float64),
+         per_run(max_evals, _NO_MAX_E, np.int64)))
     with enable_x64():
-        rows_d = jnp.asarray(rows_matrix)
-        if seen is None:
-            fresh = jnp.ones(rows_matrix.shape, dtype=bool)
-        else:
-            fresh = ~jnp.asarray(seen)[rows_d] if np.asarray(seen).ndim == 1 \
-                else ~jnp.take_along_axis(jnp.asarray(seen), rows_d, axis=1)
-
-        def per_run(x, default, dtype):
-            if x is None:
-                x = default
-            arr = (f64_bits(x) if dtype == np.float64
-                   else np.asarray(x, dtype=dtype))
-            return jnp.broadcast_to(jnp.asarray(arr), (runs,))
-
-        out = _replay_vjit(
-            rows_d, fresh, tables.col_of_row, tables.time_s, tables.charge_s,
-            jnp.asarray(f64_bits(mean_charge)),
-            per_run(spent0, 0.0, np.float64),
-            per_run(evals0, 0, np.int64),
-            per_run(max_seconds, _NO_MAX_S, np.float64),
-            per_run(max_evals, _NO_MAX_E, np.int64))
-        return _to_host(out)
+        out = _replay_vjit(rows_d, fresh_d, tables.col_of_row, tables.time_s,
+                           tables.charge_s, mean_charge_d, *budgets)
+    return _to_host(tables, out)

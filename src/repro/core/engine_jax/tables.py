@@ -17,6 +17,7 @@ globally).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import enable_x64
@@ -35,9 +36,15 @@ def as_f64(bits) -> np.ndarray:
 class ReplayTables:
     """Replay-from-log tables for one (CacheColumns, CompiledSpace) pair:
     the space-row -> cache-row bridge plus the value/charge columns (as
-    float64 bit patterns)."""
+    float64 bit patterns).
 
-    __slots__ = ("n_valid", "col_of_row", "time_s", "charge_s", "has_miss")
+    A replay dispatch against these tables moves its per-call data with
+    ``device_put`` (one transfer call for all its inputs) and
+    ``device_get`` (one for all the outputs the host reads); ``transfers``
+    counts those calls, two a dispatch."""
+
+    __slots__ = ("n_valid", "col_of_row", "time_s", "charge_s", "has_miss",
+                 "transfers")
 
     def __init__(self, cols, compiled):
         col_map = cols.rows_for_space(compiled)
@@ -47,6 +54,21 @@ class ReplayTables:
             self.charge_s = jnp.asarray(f64_bits(cols.charge_s))
         self.n_valid = int(compiled.n_valid)
         self.has_miss = bool((col_map < 0).any()) if len(col_map) else False
+        self.transfers = 0
+
+    def device_put(self, inputs: tuple) -> tuple:
+        """A dispatch's host inputs on the device, in one transfer call.
+        Under ``enable_x64``, so int64 bit patterns stay int64."""
+        self.transfers += 1
+        with enable_x64():
+            return jax.device_put(inputs)
+
+    def device_get(self, outputs: tuple) -> tuple:
+        """A dispatch's outputs on the host, in one transfer call: every
+        copy starts before any is waited on, so their latencies overlap
+        instead of adding up."""
+        self.transfers += 1
+        return jax.device_get(outputs)
 
 
 class SpaceTables:

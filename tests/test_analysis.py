@@ -266,6 +266,27 @@ class TestDeviceSyncRule:
                     run.spent = float(spent[i])
         """, path=self.PATH) == []
 
+    @pytest.mark.parametrize("fetch", ["jax.device_get", "tables.device_get"])
+    def test_batched_fetch_result_is_host(self, fetch):
+        # one batched fetch of the outputs (campaign._drive_group): what it
+        # returns is on the host, so the commit loop syncs nothing
+        assert lint(f"""
+            def commit(rows, runs, tables):
+                out = _replay_vjit(rows)
+                accept, spent = {fetch}((out[0], out[4]))
+                for i, run in enumerate(runs):
+                    run.spent = float(spent[i])
+        """, path=self.PATH) == []
+
+    @pytest.mark.parametrize("fetch", ["jax.device_get", "tables.device_get"])
+    def test_batched_fetch_per_iteration_triggers(self, fetch):
+        out = lint(f"""
+            def gather(rows, tables):
+                out = jnp.stack(rows)
+                return [{fetch}(o) for o in out]
+        """, path=self.PATH)
+        assert rule_names(out) == ["device-sync-in-loop"]
+
     def test_bulk_conversion_outside_loop_passes(self):
         assert lint("""
             def once(rows):

@@ -47,6 +47,19 @@ CACHE = parity_cache()
 TOTAL = total_charge(CACHE)
 N_VALID = CACHE.space.compiled.n_valid
 
+
+def _missy_cache():
+    """The parity space with every fifth result unrecorded: those rows
+    take the imputed-miss path (value inf, the cache's mean charge)."""
+    cache = parity_cache(name="missy")
+    for key in list(cache.results)[::5]:
+        del cache.results[key]
+    cache.invalidate_columns()
+    return cache
+
+
+MISSY = _missy_cache()
+
 # (strategy, hyperparams, budget kwargs): mid-generation eval exhaustion,
 # mid-batch time exhaustion, and a natural finish (random_search is the
 # only fused strategy that stops asking on its own)
@@ -83,9 +96,14 @@ def _observable(r: SimulationRunner):
             r.budget.spent_evals, sorted(r.memo))
 
 
-def _driver(name, hp, seed, budget_kw, engine):
-    runner = SimulationRunner(CACHE, Budget(**budget_kw), engine=engine)
-    return SearchDriver(get_strategy(name, **hp), CACHE.space, runner,
+def _committed(r: SimulationRunner):
+    """Each committed observation's value, charge and status."""
+    return {k: (o.value, o.charge_s, o.status) for k, o in r.memo.items()}
+
+
+def _driver(name, hp, seed, budget_kw, engine, cache=CACHE):
+    runner = SimulationRunner(cache, Budget(**budget_kw), engine=engine)
+    return SearchDriver(get_strategy(name, **hp), cache.space, runner,
                         random.Random(seed))
 
 
@@ -101,20 +119,44 @@ def _improvements_scan(trace):
 
 
 # ----------------------------------------------------------- bit-parity
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_drive_many_device_bit_identical(seed):
+@pytest.mark.parametrize("seed,cache", [
+    (0, CACHE), (1, CACHE), (2, CACHE),
+    # imputed misses over short segments: the host's value and charge of
+    # a miss row (inf, the mean charge) against the device's, over many
+    # dispatches a run
+    (0, MISSY),
+], ids=["0", "1", "2", "missy"])
+def test_drive_many_device_bit_identical(seed, cache, monkeypatch):
     """fuse="device" commits the same observable runner state as the
     numpy oracle, case by case, and records the chosen mode."""
-    ref = [_driver(n, hp, seed + i, bk, "numpy")
+    calls = []
+    if cache is MISSY:
+        replay = engine_jax.campaign._replay_vjit
+
+        def counted(*args):
+            calls.append(1)
+            return replay(*args)
+
+        monkeypatch.setattr(engine_jax.campaign, "_replay_vjit", counted)
+        monkeypatch.setattr(engine_jax.campaign, "SEGMENT_ROWS", 40)
+    ref = [_driver(n, hp, seed + i, bk, "numpy", cache)
            for i, (n, hp, bk) in enumerate(CASES)]
-    dev = [_driver(n, hp, seed + i, bk, "jax")
+    dev = [_driver(n, hp, seed + i, bk, "jax", cache)
            for i, (n, hp, bk) in enumerate(CASES)]
     drive_many(ref)
     drive_many(dev, fuse="device")
     for (name, _hp, _bk), a, b in zip(CASES, ref, dev):
         assert b.fuse == "device", name
         assert _observable(a.runner) == _observable(b.runner), name
+        assert _committed(a.runner) == _committed(b.runner), name
         assert a.exhausted == b.exhausted, name
+    if cache is MISSY:
+        assert len(calls) > 10
+        mean = cache.mean_eval_charge()
+        assert any(o.status == "error" and not o.result.times_s
+                   and o.charge_s == mean
+                   for b in dev for o in b.runner.memo.values()), \
+            "expected committed imputed misses"
 
 
 def test_fused_group_matches_isolated_runs():

@@ -24,6 +24,7 @@ from repro.core import spans
 from repro.core.budget import Budget
 from repro.core.driver import SearchDriver
 from repro.core.engine_jax import campaign
+from repro.core.engine_jax.tables import ReplayTables, replay_tables
 from repro.core.methodology import evaluate_strategy, make_scorer
 from repro.core.parallel import CampaignJournal
 from repro.core.runner import LiveRunner, SimulationRunner
@@ -141,6 +142,55 @@ def test_a_fused_campaign_writes_one_dispatch_span_per_replay_dispatch(
     for _name, s, _e in (ev for ev in found
                          if ev[0] == spans.REPLAY_DISPATCH):
         assert any(e0 <= s for _n, _s0, e0 in steps)
+
+
+def _fetch_sizes(monkeypatch):
+    """Record how many arrays each batched fetch brings back."""
+    sizes = []
+    fetch = ReplayTables.device_get
+
+    def spy(self, outputs):
+        sizes.append(len(outputs))
+        return fetch(self, outputs)
+
+    monkeypatch.setattr(ReplayTables, "device_get", spy)
+    return sizes
+
+
+def test_a_fused_dispatch_makes_one_put_and_one_fetch(monkeypatch):
+    calls = []
+    replay = campaign._replay_vjit
+
+    def counted(*args):
+        calls.append(1)
+        return replay(*args)
+
+    monkeypatch.setattr(campaign, "_replay_vjit", counted)
+    monkeypatch.setattr(campaign, "SEGMENT_ROWS", 40)
+    sizes = _fetch_sizes(monkeypatch)
+    tables = replay_tables(CACHE.columns, CACHE.space.compiled)
+    before = tables.transfers
+    engine_jax.drive_fused([_ga_driver(seed) for seed in range(3)])
+    assert len(calls) > 1
+    assert tables.transfers - before == 2 * len(calls)
+    # accept, t_after, spent, evals, exhausted: value and charge are the
+    # host's own gathers, never fetched
+    assert sizes == [5] * len(calls)
+
+
+def test_a_per_ask_dispatch_makes_one_put_and_one_fetch(monkeypatch):
+    sizes = _fetch_sizes(monkeypatch)
+    runner = SimulationRunner(CACHE, Budget(max_seconds=1e9), engine="jax")
+    tables = replay_tables(CACHE.columns, CACHE.space.compiled)
+    before = tables.transfers
+    for rows in ([0], [1, 2, 3], [0], [4, 5]):
+        runner.run_batch(RowBatch(CACHE.space.compiled,
+                                  np.asarray(rows, dtype=np.int64)))
+    dispatches = runner._jax_engine().dispatches
+    assert dispatches == 3  # the revisit of row 0 dispatches nothing
+    assert tables.transfers - before == 2 * dispatches
+    # the Observations need value and charge: all seven outputs
+    assert sizes == [7] * dispatches
 
 
 def test_a_per_ask_replay_writes_one_dispatch_span_per_dispatch(tmp_path):

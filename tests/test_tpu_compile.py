@@ -109,15 +109,16 @@ def test_vmem_overflow_is_refused_for_v5e(one_chip):
 
 def test_replay_vjit_compiles_for_v5e(one_chip):
     """The fused campaign's budget scan at one Table III dispatch: 25 runs
-    x 1024 rows over the 10,140-row GEMM table. Its float64 columns are
-    int64 bit patterns, added in integer arithmetic: nothing in the
-    compiled program may be a float64, which the TPU would split into a
-    pair of float32."""
+    x 1024 rows over the 10,140-row GEMM table, the rows as int32, as
+    ``campaign._drive_group`` sends them. Its float64 columns are int64
+    bit patterns, added in integer arithmetic: nothing in the compiled
+    program may be a float64, which the TPU would split into a pair of
+    float32."""
     runs, rows, table = 25, 1024, 10_140
     with jax.enable_x64():
         compiled = _compile(
             _replay_vjit, one_chip,
-            ((runs, rows), jnp.int64), ((runs, rows), jnp.bool_),
+            ((runs, rows), jnp.int32), ((runs, rows), jnp.bool_),
             ((table,), jnp.int32), ((table,), jnp.int64),
             ((table,), jnp.int64), ((), jnp.int64),
             ((runs,), jnp.int64), ((runs,), jnp.int64),
