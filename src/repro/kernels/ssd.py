@@ -1,23 +1,35 @@
 """Mamba2 SSD (state-space duality) chunked scan — TPU Pallas kernel.
 
-The attention-free hot spot for mamba2/zamba2. Implements the SSD chunked
-algorithm (Dao & Gu, arXiv:2405.21060) for one head group:
+The attention-free hot spot of Mamba-2 and its hybrids (Nemotron-H,
+Zamba2). Implements the SSD chunked algorithm (Dao & Gu, arXiv:2405.21060)
+for every head h, whose B and C are those of its group g(h):
 
-    h_t = exp(dt_t·A) · h_{t-1} + dt_t · B_t ⊗ x_t          (state update)
-    y_t = C_t · h_t                                          (readout)
+    h_t = exp(dt_t·a_h) · h_{t-1} + dt_t · B_{g,t} ⊗ x_t     (state update)
+    y_t = C_{g,t} · h_t                                       (readout)
 
-Chunked over the sequence: within a chunk of Q steps the output splits into
-an *intra-chunk* quadratic term ((C Bᵀ) ∘ decay-mask) X — two MXU matmuls —
-and an *inter-chunk* term C · (decay · h_in); the carried state is updated
-with a third matmul. The chunk loop is the innermost ("arbitrary") grid dim
-with the state in VMEM scratch — the TPU-native replacement for the paper's
-GPU warp-level scan.
+B and C are held per group, ``(G, L, N)``, as the published mixers hold
+them (``n_groups``): head h reads group ``h // (BH / G)``, as
+``flash_attention``'s query heads read their KV head. G = BH is the
+ungrouped scan.
 
-Tunables: chunk length Q, state block, accumulate dtype.
+Chunked over the sequence: within a chunk of Q steps the output splits
+into an *intra-chunk* quadratic term ((C Bᵀ) ∘ decay-mask) X — two MXU
+matmuls — and an *inter-chunk* term C · (decay · h_in); the carried state
+is updated with a third matmul. A grid step takes ``head_block`` heads of
+one group: it fetches the group's chunk of B and C once and computes C·Bᵀ
+(2Q²N, the largest term at N = 256) once for all of them, then loops over
+the heads, each with its (N, P) state in VMEM scratch. The chunk loop is
+the innermost ("arbitrary") grid dim — the TPU-native replacement for the
+paper's GPU warp-level scan.
+
+Tunables: the chunk length Q (``chunk``) and the heads a grid step takes
+(``head_block``, dividing BH / G). Each pair is its own program; the
+accumulator is always float32.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Mapping
 
 import jax
@@ -30,111 +42,168 @@ from ..core.devices import DeviceModel
 from ..core.searchspace import SearchSpace
 from ..core.tunable import Constraint, tunables_from_dict
 
-# Recording problem size (CPU interpret-mode live tuning)
-SMOKE_PROBLEM = {"bh": 4, "seq": 256, "p": 32, "n": 32}
+# Recording problem size (CPU interpret-mode live tuning): 4 heads sharing
+# one group of B/C
+SMOKE_PROBLEM = {"bh": 4, "bh_g": 1, "seq": 256, "p": 32, "n": 32}
+
+# Nemotron-H-47B's Mamba-2 mixer at batch 1 and its 8192-token context:
+# mamba_num_heads 256, n_groups 8, mamba_head_dim 64, ssm_state_size 256
+DEFAULT_PROBLEM = {"bh": 256, "bh_g": 8, "seq": 8192, "p": 64, "n": 256}
+
+_TILE = (8, 128)  # a float32 VMEM tile: (sublanes, lanes)
+# float32 matmuls in float32 passes: at the chip's default precision the
+# MXU rounds x, B, C and the state to bfloat16, and on a TPU v5e the scan's
+# error (0.10 to 0.13 at |y| up to 29, seq 8192, N 256) came near that of
+# a scan computed in bfloat16 throughout (0.17 to 0.23)
+_HI = jax.lax.Precision.HIGHEST
 
 
-def _ssd_kernel(x_ref, dt_row_ref, dt_col_ref, a_ref, b_ref, c_ref, y_ref,
-                h_ref, *, chunk: int):
+def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
+                chunk: int, head_block: int):
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0].astype(jnp.float32)            # (Q, P)
-    dt_row = dt_row_ref[0, pl.ds(ci, 1), :]     # (1, Q)
-    dt_col = dt_col_ref[0]                      # (Q, 1)
-    a = a_ref[0]                                # (1, 1) scalar A (negative)
+    # the group's chunk of B and C, and C·Bᵀ, once for the block's heads
     b = b_ref[0].astype(jnp.float32)            # (Q, N)
     c = c_ref[0].astype(jnp.float32)            # (Q, N)
-
-    # within-chunk prefix sums of the log per-step decay dt·A, as matmuls
-    # with triangular masks: Mosaic lowers no cumsum, and broadcasts only
-    # along one of sublanes/lanes at a time, so dt arrives both as a row
-    # and as a column. cum_i[i, :] = cum_i and cum_j[:, j] = cum_j.
-    hi = jax.lax.Precision.HIGHEST
+    cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
+                             precision=_HI,
+                             preferred_element_type=jnp.float32)  # (Q, Q)
+    n = h_ref.shape[1]
     iota_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     iota_j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    lower = iota_i >= iota_j
-    ld_col = dt_col * a
-    cum_i = jax.lax.dot(lower.astype(jnp.float32),
-                        jnp.broadcast_to(ld_col, (chunk, chunk)),
-                        precision=hi, preferred_element_type=jnp.float32)
-    cum_j = jax.lax.dot(jnp.broadcast_to(dt_row * a, (chunk, chunk)),
-                        (iota_i <= iota_j).astype(jnp.float32),
-                        precision=hi, preferred_element_type=jnp.float32)
-    cum = cum_i[:, :1]                          # (Q, 1)
-    total = cum_j[:1, chunk - 1:]               # (1, 1)
-    # intra-chunk: mask[i,j] = exp(cum_i - cum_j) for j <= i (strict decay
-    # between step j and i), scaled by dt_j
-    decay_ij = jnp.where(lower, jnp.exp(cum_i - cum_j), 0.0)
-    cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (Q, Q)
-    w = cb * decay_ij * dt_row
-    y_intra = jax.lax.dot(w, x, preferred_element_type=jnp.float32)
+    lower = iota_i >= iota_j                    # step j at or before i
+    square = (chunk, chunk)
 
-    # inter-chunk: y_inter_i = exp(cum_i) * C_i · h_in
-    h_in = h_ref[...]                           # (N, P)
-    y_inter = jnp.exp(cum) * jax.lax.dot(
-        c, h_in, preferred_element_type=jnp.float32)
+    def head(k, carry):
+        x = x_ref[k].astype(jnp.float32)        # (Q, P)
+        dt_row = dt_ref[k, pl.ds(ci, 1), :]     # (1, Q)
+        a = a_ref[k]                            # (1, 1): the head's A < 0
+        # Within-chunk prefix sums of the log per-step decay dt·A, as
+        # masked sums on the VPU, exact in float32. Mosaic lowers no
+        # cumsum and no transpose of a row, and broadcasts along one of
+        # sublanes/lanes at a time: the row is spread over the square and
+        # summed along lanes for the column forms, the column spread and
+        # summed along sublanes for the row form.
+        dt_col = jnp.sum(jnp.where(iota_i == iota_j,
+                                   jnp.broadcast_to(dt_row, square), 0.0),
+                         axis=1, keepdims=True)                 # (Q, 1)
+        ld_row, ld_col = dt_row * a, dt_col * a
+        cum_i = jnp.sum(jnp.where(lower, jnp.broadcast_to(ld_row, square),
+                                  0.0), axis=1, keepdims=True)  # (Q, 1)
+        cum_j = jnp.sum(jnp.where(iota_i <= iota_j,
+                                  jnp.broadcast_to(ld_col, square), 0.0),
+                        axis=0, keepdims=True)                  # (1, Q)
+        # intra-chunk: w[i, j] = (C_i·B_j) exp(cum_i - cum_j) dt_j, j <= i
+        decay_ij = jnp.where(lower, jnp.exp(cum_i - cum_j), 0.0)
+        w = cb * decay_ij * dt_row
+        y_intra = jax.lax.dot(w, x, precision=_HI,
+                              preferred_element_type=jnp.float32)
+        # inter-chunk: y_inter_i = exp(cum_i) C_i · h_in
+        h_in = h_ref[k]                         # (N, P)
+        y_inter = jnp.exp(cum_i) * jax.lax.dot(
+            c, h_in, precision=_HI, preferred_element_type=jnp.float32)
+        y_ref[k] = (y_intra + y_inter).astype(y_ref.dtype)
+        # state: h_out = exp(total) h_in + Σ_j exp(total - cum_j) dt_j B_j⊗X_j
+        total = cum_i[chunk - 1:, :]            # (1, 1)
+        suffix = jnp.exp(total - cum_i) * dt_col                # (Q, 1)
+        bx = jax.lax.dot_general(b * suffix, x, (((0,), (0,)), ((), ())),
+                                 precision=_HI,
+                                 preferred_element_type=jnp.float32)
+        # exp(total) as an (N, 1) column: a (1, 1) -> (N, P) broadcast
+        # would cross sublanes and lanes at once
+        decay_n = jnp.exp(jnp.sum(jnp.broadcast_to(ld_row, (n, chunk)),
+                                  axis=1, keepdims=True))
+        h_ref[k] = decay_n * h_in + bx
+        return carry
 
-    y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
-
-    # state update: h_out = exp(total) * h_in + Σ_j exp(total - cum_j)·dt_j·B_j⊗X_j
-    suffix = jnp.exp(total - cum) * dt_col      # (Q, 1)
-    bx = jax.lax.dot_general(b * suffix, x, (((0,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (N, P)
-    # exp(total) as an (N, 1) column: a (1, 1) -> (N, P) broadcast would
-    # cross sublanes and lanes at once
-    decay_n = jnp.exp(jax.lax.dot(
-        jnp.ones((h_in.shape[0], chunk), jnp.float32), ld_col,
-        precision=hi, preferred_element_type=jnp.float32))
-    h_ref[...] = decay_n * h_in + bx
+    jax.lax.fori_loop(0, head_block, head, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _padded(*shape: int) -> int:
+    """Elements of a float32 array in VMEM, its last two dims padded to
+    whole (8, 128) tiles."""
+    *lead, rows, cols = (1,) * (2 - len(shape)) + shape
+    count = -(-rows // _TILE[0]) * _TILE[0] * (-(-cols // _TILE[1]) * _TILE[1])
+    for d in lead:
+        count *= d
+    return count
+
+
+def vmem_bytes(chunk: int, head_block: int, seq: int, p: int, n: int) -> int:
+    """VMEM one grid step holds: the double-buffered blocks of x, y, dt,
+    A, B and C, the block's states and the (Q, Q) temporaries of a head."""
+    n_chunks = seq // chunk
+    blocks = (2 * _padded(head_block, chunk, p)        # x and y
+              + _padded(head_block, n_chunks, chunk)   # dt rows
+              + _padded(head_block, 1, 1)              # A
+              + 2 * _padded(chunk, n))                 # B and C
+    return 4 * (2 * blocks + _padded(head_block, n, p)
+                + 6 * _padded(chunk, chunk))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("chunk", "head_block", "interpret"))
 def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
-             c: jax.Array, *, chunk: int = 128,
+             c: jax.Array, *, chunk: int = 128, head_block: int = 1,
              interpret: bool = False) -> jax.Array:
-    """SSD scan for flattened (batch·heads) leading dim.
+    """SSD scan over a flattened (batch·heads) leading dim, B/C grouped.
 
-    x: (BH, L, P); dt: (BH, L); a: (BH,); b/c: (BH, L, N). Returns y like x.
-    ``dt`` and ``a`` are passed in blocks whose last two dimensions equal
-    the array's, which the TPU's (8, 128) tiling rule accepts at any chunk.
+    x: (BH, L, P); dt: (BH, L); a: (BH,); b/c: (G, L, N) with G dividing
+    BH, head h reading group h // (BH / G). ``head_block`` divides BH / G.
+    Returns y like x. ``dt`` and ``a`` are passed in blocks whose last two
+    dimensions equal the array's, which the TPU's (8, 128) tiling rule
+    accepts at any chunk.
     """
     bh, l, p = x.shape
-    n = b.shape[-1]
+    g, _, n = b.shape
+    assert bh % g == 0 and (bh // g) % head_block == 0, (bh, g, head_block)
     assert l % chunk == 0
     n_chunks = l // chunk
-    kernel = functools.partial(_ssd_kernel, chunk=chunk)
+    per_group = bh // g // head_block   # head blocks in one group
+    kernel = functools.partial(_ssd_kernel, chunk=chunk,
+                               head_block=head_block)
+    heads = pl.BlockSpec((head_block, chunk, p), lambda i, j: (i, j, 0))
+    group = pl.BlockSpec((1, chunk, n),
+                         lambda i, j, r=per_group: (i // r, j, 0))
+    # Mosaic's default scoped VMEM (16 MiB) is below what the larger
+    # blocks hold; the limit asks for what they need
+    limit = max(16 * 2**20, int(1.25 * vmem_bytes(chunk, head_block, l, p,
+                                                  n)))
     return pl.pallas_call(
         kernel,
-        grid=(bh, n_chunks),
+        grid=(bh // head_block, n_chunks),
         in_specs=[
-            pl.BlockSpec((1, chunk, p), lambda h, i: (h, i, 0)),
-            pl.BlockSpec((1, n_chunks, chunk), lambda h, i: (h, 0, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda h, i: (h, i, 0)),
-            pl.BlockSpec((1, 1, 1), lambda h, i: (h, 0, 0)),
-            pl.BlockSpec((1, chunk, n), lambda h, i: (h, i, 0)),
-            pl.BlockSpec((1, chunk, n), lambda h, i: (h, i, 0)),
+            heads,
+            pl.BlockSpec((head_block, n_chunks, chunk),
+                         lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((head_block, 1, 1), lambda i, j: (i, 0, 0)),
+            group,
+            group,
         ],
-        out_specs=pl.BlockSpec((1, chunk, p), lambda h, i: (h, i, 0)),
+        out_specs=heads,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((head_block, n, p), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=limit),
         interpret=interpret,
-    )(x, dt.reshape(bh, n_chunks, chunk), dt.reshape(bh, l, 1),
-      a.reshape(bh, 1, 1), b, c)
+        name="ssd_scan",
+    )(x, dt.reshape(bh, n_chunks, chunk), a.reshape(bh, 1, 1), b, c)
 
 
 # -------------------------------------------------------------------- ref
 def ssd_ref(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
             c: jax.Array, **_unused) -> jax.Array:
-    """Sequential oracle: literal recurrence, step by step."""
+    """Sequential oracle: literal recurrence, step by step, B/C grouped
+    as ``ssd_scan`` takes them."""
     bh, l, p = x.shape
-    n = b.shape[-1]
+    g, _, n = b.shape
+    b = jnp.repeat(b, bh // g, axis=0)
+    c = jnp.repeat(c, bh // g, axis=0)
 
     def step(h, inputs):
         x_t, dt_t, b_t, c_t = inputs  # (BH,P), (BH,), (BH,N), (BH,N)
@@ -154,66 +223,93 @@ def ssd_ref(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
 
 
 # ------------------------------------------------------------ search space
+_DRAW = (256, 1024)  # the standard normals one step of ``_normal`` draws
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _normal(key: jax.Array, shape: tuple) -> jax.Array:
+    """Standard normal float32 of ``shape``, in row-major order, drawn
+    ``_DRAW`` at a time from the keys ``fold_in(key, i)``, i = 0, 1, ...
+    The TPU compiler takes 16 to 34 s over one draw of a (256, 8192, 64)
+    or (8, 8192, 256) array, and under a second over this loop; a cold
+    recording compiles it anew."""
+    size = math.prod(shape)
+    steps = -(-size // math.prod(_DRAW))
+    draws = jax.lax.map(
+        lambda i: jax.random.normal(jax.random.fold_in(key, i), _DRAW,
+                                    jnp.float32), jnp.arange(steps))
+    return draws.reshape(-1)[:size].reshape(shape)
+
+
+def live_inputs(seed: int, bh: int, bh_g: int, seq: int, p: int, n: int):
+    """``(x, dt, a, b, c)`` from one key, split five ways in that order:
+    x, B and C standard normal (``_normal``); dt uniform in [0.001, 0.1]
+    (``time_step_min``, ``time_step_max``); A = -uniform[1, 16], Mamba-2's
+    ``A_log`` initialisation. All float32."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = _normal(ks[0], (bh, seq, p))
+    dt = jax.random.uniform(ks[1], (bh, seq), jnp.float32, 0.001, 0.1)
+    a = -jax.random.uniform(ks[2], (bh,), jnp.float32, 1.0, 16.0)
+    b = _normal(ks[3], (bh_g, seq, n))
+    c = _normal(ks[4], (bh_g, seq, n))
+    return x, dt, a, b, c
+
+
 def make_live(problem: Mapping | None, interpret: bool):
-    """Recorder callable: chunked SSD scan on fixed inputs; state_block and
-    accumulator-dtype tunables are cost-model-only."""
+    """Recorder callable: the grouped chunked scan on fixed inputs made
+    from the problem's sizes and ``seed``."""
     p = {**SMOKE_PROBLEM, **(problem or {})}
-    ks = jax.random.split(jax.random.PRNGKey(p.get("seed", 9)), 5)
-    bh, l = p["bh"], p["seq"]
-    x = jax.random.normal(ks[0], (bh, l, p["p"]), jnp.float32)
-    dt = jax.random.uniform(ks[1], (bh, l), jnp.float32, 0.001, 0.1)
-    a = -jax.random.uniform(ks[2], (bh,), jnp.float32, 0.5, 1.5)
-    b = jax.random.normal(ks[3], (bh, l, p["n"]), jnp.float32)
-    c = jax.random.normal(ks[4], (bh, l, p["n"]), jnp.float32)
+    x, dt, a, b, c = live_inputs(p.get("seed", 9), p["bh"], p["bh_g"],
+                                 p["seq"], p["p"], p["n"])
 
     def fn(conf: Mapping) -> None:
         out = ssd_scan(x, dt, a, b, c, chunk=conf["chunk"],
-                       interpret=interpret)
+                       head_block=conf["head_block"], interpret=interpret)
         jax.block_until_ready(out)
 
     return fn
 
 
-def space(seq: int = 4096) -> SearchSpace:
+def space(seq: int = DEFAULT_PROBLEM["seq"], bh: int = DEFAULT_PROBLEM["bh"],
+          bh_g: int = DEFAULT_PROBLEM["bh_g"]) -> SearchSpace:
     tunables = tunables_from_dict({
         "chunk": (32, 64, 128, 256, 512),
-        "acc_dtype": ("f32", "bf16"),
-        "state_block": (32, 64, 128),
+        "head_block": (1, 2, 4, 8, 16, 32),
     })
     constraints = (
         Constraint(lambda c: seq % c["chunk"] == 0, "chunk divides L"),
-        Constraint(lambda c: c["state_block"] <= 128, "state fits a tile"),
+        Constraint(lambda c: (bh // bh_g) % c["head_block"] == 0,
+                   "head_block divides BH/G"),
     )
     return SearchSpace(tunables, constraints, name="ssd")
 
 
-def workload(bh: int = 24 * 8, seq: int = 4096, p: int = 64,
-             n: int = 128) -> KernelWorkload:
+def workload(bh: int = DEFAULT_PROBLEM["bh"],
+             bh_g: int = DEFAULT_PROBLEM["bh_g"],
+             seq: int = DEFAULT_PROBLEM["seq"], p: int = DEFAULT_PROBLEM["p"],
+             n: int = DEFAULT_PROBLEM["n"]) -> KernelWorkload:
     def flops(c: Mapping) -> float:
-        q = c["chunk"]
-        per_chunk = 2 * q * q * n + 2 * q * q * p + 4 * q * n * p
-        return bh * (seq // q) * per_chunk
+        q, blocks = c["chunk"], bh // c["head_block"]
+        per_head = 2 * q * q * p + 4 * q * n * p   # W·X, C·h, Bᵀ·X
+        return (seq // q) * (bh * per_head + blocks * 2 * q * q * n)
 
     def hbm_bytes(c: Mapping, dev: DeviceModel) -> float:
-        return bh * seq * (p + 2 * n + 1) * 2 * 2  # in+out streams, bf16
+        # float32: x read and y written once, dt once, B and C once per
+        # head block
+        blocks = bh // c["head_block"]
+        return 4.0 * seq * (2 * bh * p + bh + 2 * blocks * n)
 
-    def vmem_bytes(c: Mapping) -> float:
-        q = c["chunk"]
-        acc = 4 if c["acc_dtype"] == "f32" else 2
-        return (2 * (q * p + 2 * q * n + q) * 2 + q * q * acc + n * p * 4
-                + q * p * acc)
+    def vmem(c: Mapping) -> float:
+        return vmem_bytes(c["chunk"], c["head_block"], seq, p, n)
 
     def grid_size(c: Mapping) -> float:
-        return bh * (seq // c["chunk"])
+        return bh // c["head_block"] * (seq // c["chunk"])
 
     def compute_eff(c: Mapping, dev: DeviceModel) -> float:
         q = c["chunk"]
         eff = alignment_eff(q, dev.mxu) * alignment_eff(n, dev.lane)
         eff *= min(1.0, q / dev.mxu) ** 0.5
-        if c["acc_dtype"] == "bf16":
-            eff *= 0.93
-        eff *= {32: 0.9, 64: 1.0, 128: 1.0}[c["state_block"]]
         return 0.7 * eff  # cumsum/exp VPU work between matmuls
 
-    return KernelWorkload("ssd", flops, hbm_bytes, vmem_bytes, grid_size,
+    return KernelWorkload("ssd", flops, hbm_bytes, vmem, grid_size,
                           compute_eff)
