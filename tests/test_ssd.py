@@ -11,15 +11,30 @@ from repro.kernels import get_kernel, ssd
 
 BH, L, P, N = 4, 128, 16, 32
 
+
+def _case(bh, groups, head_block, p, chunk, seq, name):
+    return pytest.param(bh, groups, head_block, p, chunk, seq,
+                        id=f"{name}-{chunk}")
+
+
 # (G, head_block) with head_block dividing the BH / G heads of a group
 GROUPINGS = [(1, 1), (1, 2), (1, 4), (BH // 2, 1), (BH // 2, 2), (BH, 1)]
+# at the published head dim P = 64 the kernel's head loop takes two heads
+# a pass, their states stacked; a block of all 8 heads of a group takes
+# four passes
+CASES = ([_case(BH, g, hb, P, chunk, L, f"{g}-{hb}")
+          for g, hb in GROUPINGS for chunk in (32, 64)]
+         + [_case(4, 1, hb, 64, chunk, L, f"p64-1-{hb}")
+            for hb in (2, 4) for chunk in (32, 64)]
+         + [_case(8, 1, 8, 64, chunk, 512, "p64-1-8")
+            for chunk in (64, 128, 256)])
 
 
-@pytest.mark.parametrize("chunk", [32, 64])
-@pytest.mark.parametrize("groups,head_block", GROUPINGS)
-def test_grouped_scan_matches_the_grouped_oracle(groups, head_block, chunk):
-    x, dt, a, b, c = ssd.live_inputs(11, BH, groups, L, P, N)
-    assert b.shape == c.shape == (groups, L, N)
+@pytest.mark.parametrize("bh,groups,head_block,p,chunk,seq", CASES)
+def test_grouped_scan_matches_the_grouped_oracle(bh, groups, head_block, p,
+                                                 chunk, seq):
+    x, dt, a, b, c = ssd.live_inputs(11, bh, groups, seq, p, N)
+    assert b.shape == c.shape == (groups, seq, N)
     out = ssd.ssd_scan(x, dt, a, b, c, chunk=chunk, head_block=head_block,
                        interpret=True)
     ref = ssd.ssd_ref(x, dt, a, b, c)

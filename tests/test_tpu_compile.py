@@ -84,10 +84,16 @@ CASES = {
         [((192, N, 64), F32), ((192, N), F32), ((192,), F32),
          ((192, N, 64), F32), ((192, N, 64), F32)]),
     # Nemotron-H-47B's mixer: 256 heads, B/C in 8 groups, state 256, seq
-    # 8192; the smallest and the largest blocks of the recorded space
+    # 8192; the smallest and the largest blocks of the recorded space, and
+    # its fastest program, whose head loops pass several heads at once
     "ssd-grouped-32/1": (
         lambda x, dt, a, b, c: ssd.ssd_scan(x, dt, a, b, c, chunk=32,
                                             head_block=1),
+        [((256, 8192, 64), F32), ((256, 8192), F32), ((256,), F32),
+         ((8, 8192, 256), F32), ((8, 8192, 256), F32)]),
+    "ssd-grouped-128/32": (
+        lambda x, dt, a, b, c: ssd.ssd_scan(x, dt, a, b, c, chunk=128,
+                                            head_block=32),
         [((256, 8192, 64), F32), ((256, 8192), F32), ((256,), F32),
          ((8, 8192, 256), F32), ((8, 8192, 256), F32)]),
     "ssd-grouped-512/32": (
