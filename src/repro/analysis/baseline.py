@@ -1,9 +1,8 @@
 """Checked-in baseline of grandfathered findings.
 
 The baseline exists for findings that are *deliberate* — code documented
-to live outside the contract a rule encodes (e.g. the free-running
-``engine_jax/strategies.py`` loops are outside the bit-parity contract by
-design). Everything else gets fixed, not baselined.
+to live outside the contract a rule encodes. Everything else gets fixed,
+not baselined.
 
 Entries are keyed by ``(rule, path, context)`` where ``context`` is the
 stripped source line of the finding — stable under unrelated edits that

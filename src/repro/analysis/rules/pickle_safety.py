@@ -5,7 +5,7 @@ snapshots through pickle (core/parallel.py, core/driver.py). Two classes
 of objects must never reach the pickle stream:
 
   * memoized device/columnar mirrors (``CacheColumns._jax``,
-    ``CompiledSpace._jax``, ``CacheFile._columns``, ``_space_rows``) —
+    ``CacheFile._columns``, ``_space_rows``) —
     jax device arrays don't unpickle portably, and a worker must rebuild
     its mirrors against whatever backend it actually has;
   * device arrays inside ``SearchState`` subclasses — states snapshot
@@ -20,8 +20,8 @@ import re
 from ..core import ERROR, Rule, dotted
 
 # attribute names that hold device/columnar mirror caches by convention
-# (CacheColumns._jax, CompiledSpace._jax, SimulationRunner._jax_eng,
-# CacheFile._columns, CacheColumns._space_rows)
+# (CacheColumns._jax, SimulationRunner._jax_eng, CacheFile._columns,
+# CacheColumns._space_rows)
 _CACHE_ATTR = re.compile(r"^(_jax\w*|_columns|_space_rows)$")
 
 _PICKLE_HOOKS = ("__getstate__", "__reduce__", "__reduce_ex__")

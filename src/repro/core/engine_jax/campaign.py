@@ -7,8 +7,7 @@ was ~10^4 host round-trips even though the jitted replay kernel resolves
 millions of evaluations per dispatch. This module fuses the
 budget-replay-commit leg of every concurrent run into a handful of vmapped
 ``_replay_vjit`` dispatches while keeping the bit-parity contract of the
-replay-from-log tier (PR 4), not the statistical contract of the
-free-running tier (PR 6).
+per-ask replay engine.
 
 The split that makes this possible: in simulation mode an observation's
 *value* is a pure row lookup (``time_s[col_of_row[row]]``, inf for rows

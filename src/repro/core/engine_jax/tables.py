@@ -1,12 +1,12 @@
-"""Device-resident mirrors of the compiled space and cache columns.
+"""Device-resident mirrors of the cache columns, bridged to a compiled space.
 
 The numpy arrays stay the source of truth; these are one-time ``device_put``
-copies memoized on their host objects (``CacheColumns._jax``,
-``CompiledSpace._jax``) with the same single-entry protocol as
-``CacheColumns.rows_for_space``. They are never pickled: both hosts drop
-the memo in ``__getstate__``/``__reduce__`` paths, so a process-pool worker
-rebuilds its tables against whatever backend it actually has
-(tests/test_parallel.py pins this).
+copies memoized on the cache's columns (``CacheColumns._jax``) with the
+same single-entry protocol as ``CacheColumns.rows_for_space``, keyed by
+the compiled space. They are never pickled: ``CacheColumns`` drops the
+memo in ``__getstate__``, so a process-pool worker rebuilds its tables
+against whatever backend it actually has (tests/test_parallel.py pins
+this).
 
 The cache's float64 charge/time columns are held as their int64 bit
 patterns (``f64_bits``), and every table is created under
@@ -71,24 +71,6 @@ class ReplayTables:
         return jax.device_get(outputs)
 
 
-class SpaceTables:
-    """Free-running tables for one ``CompiledSpace``: the value-index
-    matrix, validity lookup, and strides (device-side decode/repair)."""
-
-    __slots__ = ("n_valid", "n_tunables", "cards", "vidx", "row_of_flat",
-                 "strides", "x_hi")
-
-    def __init__(self, compiled):
-        with enable_x64():
-            self.vidx = jnp.asarray(compiled.vidx, dtype=jnp.int32)
-            self.row_of_flat = jnp.asarray(compiled.row_of_flat)
-            self.strides = jnp.asarray(compiled.strides_np)
-            self.x_hi = jnp.asarray(compiled._x_hi)
-        self.n_valid = int(compiled.n_valid)
-        self.n_tunables = int(compiled.n_tunables)
-        self.cards = tuple(compiled.cards)
-
-
 def replay_tables(cols, compiled) -> ReplayTables:
     """Memoized ``ReplayTables`` (single-entry, keyed by compiled-space
     identity — like ``CacheColumns.rows_for_space``)."""
@@ -99,10 +81,3 @@ def replay_tables(cols, compiled) -> ReplayTables:
     cols._jax = (compiled, tables)
     return tables
 
-
-def space_tables(compiled) -> SpaceTables:
-    """Memoized ``SpaceTables`` on the compiled space itself."""
-    tables = compiled._jax
-    if tables is None:
-        tables = compiled._jax = SpaceTables(compiled)
-    return tables

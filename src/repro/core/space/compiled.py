@@ -74,20 +74,14 @@ class CompiledSpace:
         # dict hit is ~4x cheaper than recomputing the flat index (the old
         # implementation's _repair/_validity dict caches, consolidated)
         self._repair_tuples: dict = {}
-        # device-array mirror (core.engine_jax.SpaceTables), never pickled
-        self._jax = None
 
     def __getstate__(self) -> dict:
-        """Pickle only the compiled core. Lazy boundary tables rebuild on
-        demand, and device arrays must never cross a process boundary: a
-        pool worker re-materializes them against whatever backend it
-        actually has (CPU jit, or none — the numpy engine), instead of
-        inheriting handles to a device that does not exist in its process
-        (tests/test_parallel.py pins this)."""
+        """Pickle only the compiled core: lazy boundary tables rebuild on
+        demand in the process that unpickles it."""
         state = self.__dict__.copy()
         state.update(_configs=None, _idx_tuples=None, _ids=None,
                      _id_to_row=None, _csr={}, _repair_state=None,
-                     _repair_tuples={}, _jax=None)
+                     _repair_tuples={})
         return state
 
     # ------------------------------------------------------- boundary tables

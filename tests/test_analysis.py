@@ -547,10 +547,9 @@ class TestLiveTree:
                          baseline=str(BASELINE))
         assert res.stale_baseline == []
         # the grandfathered findings are exactly the deliberate ones:
-        # the free-running tier (strategies.py) and the per-output bulk
-        # conversions after a replay dispatch (replay.py / strategies.py)
-        assert all(f.path in ("core/engine_jax/strategies.py",
-                              "core/engine_jax/replay.py")
+        # the per-output bulk conversions after a replay dispatch
+        # (replay.py)
+        assert all(f.path in ("core/engine_jax/replay.py",)
                    for f in res.baselined)
 
     def test_api_entry_point(self):

@@ -18,6 +18,9 @@ exhaustion points, and trace order. These tests pin that contract over
   * the fallback protocol: ineligible strategies degrade with a one-time
     ``FuseFallbackNotice`` naming the strategy and reason, and the chosen
     mode is surfaced on drivers and ``AggregateReport.fuse``.
+  * the eligibility gate: each reason ``fuse_reason`` turns a run away,
+    and the one unbounded run it admits (random search), which fused
+    covers the space exactly.
 
 Budgets here always stay below the cache's total fresh charge: an
 over-provisioned budget can never finish a revisit-heavy population loop
@@ -36,10 +39,13 @@ from _synth import parity_cache, total_charge
 import repro.core.engine_jax as engine_jax
 from repro.core import driver as driver_mod
 from repro.core.budget import Budget
+from repro.core.cache import CacheFile
+from repro.core.devices import V5E
 from repro.core.driver import FuseFallbackNotice, SearchDriver, drive_many
 from repro.core.methodology import evaluate_strategy, make_scorer
-from repro.core.runner import SimulationRunner
+from repro.core.runner import CostModelRunner, SimulationRunner
 from repro.core.strategies import get_strategy
+from repro.kernels import gemm
 
 pytestmark = pytest.mark.jax_engine
 
@@ -281,6 +287,62 @@ def test_mid_run_resume_finishes_fused():
     drive_many([res], fuse="device")
     assert res.fuse == "device"
     assert _observable(ref.runner) == _observable(res.runner)
+
+
+# ---------------------------------------------------- eligibility gate
+def _cost_model_driver():
+    """An array-native run whose runner evaluates a model, not a log."""
+    space = gemm.space()
+    runner = CostModelRunner(space, gemm.workload(), V5E,
+                             Budget(max_evals=40))
+    return SearchDriver(get_strategy("genetic_algorithm"), space, runner,
+                        random.Random(0))
+
+
+EMPTY = CacheFile("empty", "synth", CACHE.space, {})
+
+
+@pytest.mark.parametrize("make,reason", [
+    pytest.param(lambda: _driver("genetic_algorithm", {}, 0,
+                                 {"max_evals": 40}, "scalar"),
+                 "runner is scalar", id="scalar-runner"),
+    pytest.param(_cost_model_driver, "is not a SimulationRunner",
+                 id="not-simulation-runner"),
+    pytest.param(lambda: _driver("genetic_algorithm", {}, 0,
+                                 {"max_evals": 40}, "jax", EMPTY),
+                 "cache is empty", id="empty-cache"),
+    *[pytest.param(lambda n=n: _driver(n, {}, 0, {}, "jax"),
+                   "unbounded budget", id=f"unbounded-{n}")
+      for n in ("genetic_algorithm", "pso", "differential_evolution")],
+    # random search stops asking on its own once the space is covered
+    pytest.param(lambda: _driver("random_search", {}, 0, {}, "jax"), None,
+                 id="unbounded-random_search"),
+])
+def test_fuse_reason_gates_the_device_path(make, reason):
+    """Each reason the device path turns an array-native run away, and the
+    one unbounded run it admits. Only the gate is asked: an unbounded GA
+    driven on the host would never end."""
+    got = engine_jax.fuse_reason(make())
+    if reason is None:
+        assert got is None
+    else:
+        assert got is not None and reason in got
+
+
+def test_fused_unbudgeted_random_search_covers_space_exactly():
+    """Random search with no budget, fused: every row exactly once, the
+    recorded optimum found, and the committed state bit-identical to the
+    same run on the numpy engine."""
+    ref = _driver("random_search", {}, 2, {}, "numpy")
+    dev = _driver("random_search", {}, 2, {}, "jax")
+    drive_many([ref])
+    (run,) = engine_jax.drive_fused([dev])
+    assert run.fresh_evals == dev.runner.fresh_evals == N_VALID
+    optimum = min(r.time_s for r in CACHE.results.values()
+                  if r.status == "ok")
+    assert dev.runner.best.value == optimum
+    assert not run.exhausted
+    assert _observable(dev.runner) == _observable(ref.runner)
 
 
 # ------------------------------------------------------- fallback protocol

@@ -1,18 +1,13 @@
 """Jax-engine conformance: the jitted replay path against the numpy oracle.
 
-Two contracts, tested separately (see ``core.engine_jax``):
-
-  * replay-from-log is **bit-identical** — given identical told
-    observations, ``SimulationRunner(engine="jax")`` commits the same
-    scores, traces, budget spends, and exhaustion points as the numpy
-    engine, observation for observation. Deterministic fixtures pin the
-    edge shapes (budget exhaustion mid-batch, inf failures, cache-miss
-    rows, single-row asks, revisit-only batches, empty caches) and a
-    hypothesis sweep drives random batches over one fixed space shape
-    (bounding jit recompiles to the padded power-of-two ladder);
-  * free-running is **statistically equivalent** only — device RNG cannot
-    replay numpy streams, so pinned seeds reproduce against themselves
-    and distributions (best value, spend) match the numpy strategies.
+Replay-from-log is **bit-identical** (see ``core.engine_jax``): given
+identical told observations, ``SimulationRunner(engine="jax")`` commits
+the same scores, traces, budget spends, and exhaustion points as the
+numpy engine, observation for observation. Deterministic fixtures pin
+the edge shapes (budget exhaustion mid-batch, inf failures, cache-miss
+rows, single-row asks, revisit-only batches, empty caches) and a
+hypothesis sweep drives random batches over one fixed space shape
+(bounding jit recompiles to the padded power-of-two ladder).
 
 Marked ``jax_engine``. The engine runs on whatever platform JAX
 initialized (the CPU under ``JAX_PLATFORMS=cpu``); it never degrades to
@@ -253,96 +248,6 @@ def test_replay_many_seen_basis_makes_revisits_free():
     assert evals[0] == compiled.n_valid // 2
 
 
-# ------------------------------------------------------------ free-running
-def test_free_run_pinned_seed_reproduces_bitwise():
-    a = engine_jax.free_run(CACHE, "genetic_algorithm", runs=8, seed=5,
-                            generations=12, max_seconds=TOTAL * 0.3)
-    b = engine_jax.free_run(CACHE, "genetic_algorithm", runs=8, seed=5,
-                            generations=12, max_seconds=TOTAL * 0.3)
-    for k in a:
-        assert np.array_equal(a[k], b[k]), k
-
-
-@pytest.mark.parametrize("name", sorted(engine_jax.FREE_RUN_STRATEGIES))
-def test_free_run_budget_and_shape_invariants(name):
-    runs, G = 6, 10
-    out = engine_jax.free_run(CACHE, name, runs=runs, seed=1, generations=G,
-                              max_evals=40)
-    assert out["curve_spent"].shape == (runs, G)
-    assert (out["spent_evals"] <= 40).all()
-    assert (out["fresh_evals"] == out["spent_evals"]).all()
-    # spend curves are monotone and end at the final spend
-    assert (np.diff(out["curve_spent"], axis=1) >= 0).all()
-    assert np.array_equal(out["curve_spent"][:, -1], out["spent_seconds"])
-    # best rows are valid whenever a finite best exists
-    finite = np.isfinite(out["best_value"])
-    assert (out["best_row"][finite] >= 0).all()
-    # bests are recorded values, bit for bit
-    recorded = {r.time_s for r in CACHE.results.values()} | {math.inf}
-    assert set(out["curve_best"].ravel().tolist()) <= recorded
-
-
-def test_order_key_orders_like_the_values():
-    """free_run tracks its best value as ``_order_key`` of its bit pattern:
-    integer order must be value order, signs and extremes included, and
-    the key must map back to the same bits."""
-    from repro.core.engine_jax.strategies import _order_key
-    from repro.core.engine_jax.tables import f64_bits
-    rng = np.random.default_rng(3)
-    x = np.concatenate([
-        rng.standard_normal(500) * 10.0 ** rng.integers(-300, 300, 500),
-        [0.0, 5e-324, -5e-324, 1.0, -1.0, math.inf, -math.inf]])
-    with jax.enable_x64():
-        key = np.asarray(jax.jit(_order_key)(jnp.asarray(f64_bits(x))))
-        back = np.asarray(jax.jit(_order_key)(jnp.asarray(key)))
-    assert np.array_equal(np.argsort(key), np.argsort(x))
-    assert np.array_equal(back, f64_bits(x))
-
-
-def test_free_run_random_search_exhausts_space_exactly():
-    """Unbudgeted random search over enough generations covers every row
-    exactly once: fresh == n_valid, best == optimum, spend == total charge
-    (order-independent up to float summation order)."""
-    compiled = CACHE.space.compiled
-    P = 20
-    G = -(-compiled.n_valid // P) + 2
-    out = engine_jax.free_run(CACHE, "random_search", runs=4, seed=2,
-                              generations=G, popsize=P)
-    assert (out["fresh_evals"] == compiled.n_valid).all()
-    optimum = min(r.time_s for r in CACHE.results.values()
-                  if r.status == "ok")
-    assert np.array_equal(out["best_value"],
-                          np.full(4, optimum))
-    assert np.allclose(out["spent_seconds"], TOTAL, rtol=1e-10)
-    assert not out["exhausted"].any()
-
-
-def test_free_run_statistically_matches_numpy_ga():
-    """Distribution check (deterministic given pinned seeds): mean best
-    value over jax runs lands in the same range as the numpy GA under the
-    same budget."""
-    budget = TOTAL * 0.25
-    out = engine_jax.free_run(CACHE, "genetic_algorithm", runs=24, seed=11,
-                              generations=40, max_seconds=budget)
-    np_best = []
-    for i in range(24):
-        runner = SimulationRunner(CACHE, Budget(max_seconds=budget))
-        get_strategy("genetic_algorithm").run(CACHE.space, runner,
-                                              random.Random(1000 + i))
-        np_best.append(runner.best.value)
-    jx = out["best_value"]
-    assert np.isfinite(jx).all()
-    lo, hi = min(np_best), max(np_best)
-    spread = (hi - lo) or 1e-9
-    assert abs(float(np.mean(jx)) - float(np.mean(np_best))) < 3 * spread
-
-
-def test_free_run_rejects_unknown_hyperparameters():
-    with pytest.raises(ValueError, match="unknown hyperparameters"):
-        engine_jax.free_run(CACHE, "pso", runs=2, generations=2,
-                            crossover="uniform")
-
-
 # ------------------------------------------------------ float64 on bits
 def _f64_cases():
     rng = np.random.default_rng(11)
@@ -383,8 +288,6 @@ def test_tables_are_memoized_and_x64():
     cols = CACHE.columns
     rt = engine_jax.replay_tables(cols, compiled)
     assert engine_jax.replay_tables(cols, compiled) is rt
-    st_ = engine_jax.space_tables(compiled)
-    assert engine_jax.space_tables(compiled) is st_
     # float64 columns travel as their exact bit patterns
     assert str(rt.time_s.dtype) == "int64"
     assert str(rt.charge_s.dtype) == "int64"

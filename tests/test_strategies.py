@@ -71,6 +71,17 @@ def test_hyperparameters_validated():
         get_strategy("pso", bogus=3)
 
 
+@pytest.mark.parametrize("name", sorted(STRATEGIES))
+def test_strategy_rejects_unknown_hyperparameters(name):
+    """A misspelt hyperparameter is refused by name, never run silently
+    on the defaults; the known ones passed beside it are not named."""
+    known = dict(STRATEGIES[name].DEFAULTS)
+    with pytest.raises(ValueError, match=rf"^{name}: unknown "
+                       rf"hyperparameters \['no_such_knob'\]$"):
+        get_strategy(name, **known, no_such_knob=1)
+    assert get_strategy(name, **known).hyperparams == known
+
+
 def test_paper_strategy_registry():
     assert set(PAPER_STRATEGIES) <= set(STRATEGIES)
     for name in PAPER_STRATEGIES:

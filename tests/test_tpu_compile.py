@@ -143,36 +143,3 @@ def test_replay_vjit_compiles_for_v5e(one_chip):
             ((runs,), jnp.int64), ((runs,), jnp.int64))
     assert compiled.memory_analysis() is not None
     assert "f64[" not in compiled.as_text()
-
-
-@pytest.mark.parametrize("strategy", ["genetic_algorithm", "pso",
-                                      "differential_evolution",
-                                      "random_search"])
-def test_free_run_compiles_for_v5e(one_chip, strategy):
-    """A free-running campaign in one dispatch: 32 runs x 100 generations
-    over the hub GEMM space. Its budget and its best value are int64 bit
-    patterns, as in the replay kernel, and no int64 dot is left (the TPU
-    has none)."""
-    from repro.core.engine_jax.strategies import (FREE_RUN_STRATEGIES,
-                                                  _free_run_jit)
-    from repro.core.engine_jax.tables import space_tables
-    impl = FREE_RUN_STRATEGIES[strategy]
-    hp = impl.defaults
-    space = gm.space().compiled
-    st = space_tables(space)
-    runs, rows = 32, space.n_valid
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    with jax.enable_x64():
-        compiled = _free_run_jit.lower(
-            impl, int(hp["popsize"]), 100,
-            tuple(sorted(hp.items())), st.cards,
-            arg((runs, 2), jnp.uint32), arg((rows,), jnp.int32),
-            arg((rows,), jnp.int64), arg((rows,), jnp.int64),
-            *[arg(a.shape, a.dtype)
-              for a in (st.vidx, st.row_of_flat, st.strides, st.x_hi)],
-            arg((), jnp.int64), arg((), jnp.int64),
-            arg((), jnp.int64)).compile()
-    assert rows == 10_140
-    assert compiled.memory_analysis() is not None
